@@ -1,12 +1,16 @@
 """Benchmark aggregator: one section per paper table/figure, CSV output.
 
     PYTHONPATH=src python -m benchmarks.run [--fast|--full]
+
+A section that raises prints its traceback and the run goes on to the
+next one; the run then exits non-zero, naming every failed section.
 """
 from __future__ import annotations
 
 import argparse
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -27,14 +31,17 @@ def main() -> None:
                             bench_model_addition, bench_overhead,
                             bench_pool_scale, bench_prefill,
                             bench_routerbench, bench_scenarios,
-                            bench_telemetry, roofline)
+                            bench_telemetry)
+
+    failed = []
 
     def section(title, fn):
         t0 = time.time()
         try:
             lines = fn()
-        except Exception as e:  # noqa: BLE001
-            lines = [f"# FAILED: {type(e).__name__}: {e}"]
+        except Exception:  # noqa: BLE001 — recorded, and fails the run
+            failed.append(title)
+            lines = ["# FAILED", traceback.format_exc()]
         print(f"\n== {title} ({time.time() - t0:.1f}s) ==")
         print("\n".join(lines))
         sys.stdout.flush()
@@ -84,9 +91,9 @@ def main() -> None:
                 per_task=20 if args.fast else 60, smoke=args.fast,
                 fleet=not args.fast, artifact=None))
     section("Kernels: allclose + ref timing", bench_kernels.main)
-    section("Roofline table (from dry-run records)",
-            lambda: roofline.table("experiments/dryrun"))
     print(f"\n== total {time.time() - t_start:.1f}s ==")
+    if failed:
+        sys.exit(f"{len(failed)} section(s) failed: {'; '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1,60 +1,23 @@
-"""Version-dispatch layer for JAX API drift (supported: 0.4.x and 0.5+).
+"""Small JAX helpers shared across the stack.
 
-The repo targets two JAX generations at once: the pinned floor (0.4.37,
-what this container ships) and current releases, which renamed or moved
-several APIs the stack depends on.  Every shim below follows the same
-pattern — feature-detect once at import time, prefer the modern spelling,
-fall back to the legacy one — so call sites never branch on versions.
+The repo runs on one JAX installation (see README.md "JAX version"); JAX
+APIs are called directly at their call sites.  What lives here are the
+few helpers that add behaviour on top of a JAX call:
 
-Covered drift:
-
-  =============================  ==================================  ====
-  modern (0.5+/0.6+)             legacy (0.4.x)                      shim
-  =============================  ==================================  ====
-  jax.tree.flatten_with_path     jax.tree_util.tree_flatten_with_…   tree_flatten_with_path
-  pltpu.CompilerParams           pltpu.TPUCompilerParams             tpu_compiler_params
-  jax.make_mesh(axis_types=…)    jax.make_mesh (no axis_types)       make_mesh
-  jax.sharding.AxisType.Auto     (implicit; no enum)                 auto_axis_types
-  jax.set_mesh(mesh)             ``with mesh:`` context              set_mesh
-  compiled.cost_analysis()→dict  …→[dict]                            cost_analysis
-  jax.shard_map(check_vma=…)     jax.experimental.shard_map          shard_map
-                                 .shard_map(check_rep=…)
-  =============================  ==================================  ====
-
-Adding a new shim: feature-detect with ``hasattr``/``inspect.signature``
-(never parse ``jax.__version__`` for behaviour — only export it for
-diagnostics), keep the modern call signature as the shim's signature, and
-add a case to ``tests/test_compat.py``.
+  * ``path_str`` — render a pytree key path as ``outer/inner/leaf``;
+  * ``make_mesh`` — ``jax.make_mesh`` with every axis ``AxisType.Auto``
+    (JAX's default is ``Explicit``; the sharding rules in
+    ``models/sharding.py`` are written for automatic propagation);
+  * ``donation_kwargs`` — buffer donation only where the backend
+    implements it.
 """
 from __future__ import annotations
 
-import dataclasses
-import inspect
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Sequence
 
 import jax
-import jax.tree_util
 
-JAX_VERSION: Tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit())
-
-__all__ = [
-    "JAX_VERSION", "tree_flatten_with_path", "path_str",
-    "tpu_compiler_params", "auto_axis_types", "make_mesh", "set_mesh",
-    "cost_analysis", "shard_map", "donation_kwargs",
-]
-
-
-# ---------------------------------------------------------------------------
-# Pytree paths
-# ---------------------------------------------------------------------------
-
-if hasattr(jax.tree, "flatten_with_path"):          # 0.5+
-    def tree_flatten_with_path(tree: Any, is_leaf=None):
-        return jax.tree.flatten_with_path(tree, is_leaf=is_leaf)
-else:                                               # 0.4.x
-    def tree_flatten_with_path(tree: Any, is_leaf=None):
-        return jax.tree_util.tree_flatten_with_path(tree, is_leaf=is_leaf)
+__all__ = ["path_str", "make_mesh", "donation_kwargs"]
 
 
 def path_str(path: Sequence[Any]) -> str:
@@ -63,110 +26,13 @@ def path_str(path: Sequence[Any]) -> str:
                     for p in path)
 
 
-# ---------------------------------------------------------------------------
-# Pallas TPU compiler params
-# ---------------------------------------------------------------------------
-
-_COMPILER_PARAMS_CLS = None
-
-
-def tpu_compiler_params(**kwargs):
-    """Build pltpu.CompilerParams / TPUCompilerParams, whichever exists.
-
-    Pallas is imported lazily (cached on first call) so non-kernel
-    consumers of this module (checkpointing, meshes, the train launcher)
-    don't pull in the Pallas TPU stack.  Unknown fields are dropped rather
-    than raised: compiler hints (dimension_semantics & co.) are
-    performance knobs, and a missing knob on some JAX version must not
-    break kernel construction.
-    """
-    global _COMPILER_PARAMS_CLS
-    if _COMPILER_PARAMS_CLS is None:
-        from jax.experimental.pallas import tpu as pltpu
-        _COMPILER_PARAMS_CLS = (getattr(pltpu, "CompilerParams", None)
-                                or getattr(pltpu, "TPUCompilerParams"))
-    fields = {f.name for f in dataclasses.fields(_COMPILER_PARAMS_CLS)}
-    return _COMPILER_PARAMS_CLS(**{k: v for k, v in kwargs.items()
-                                   if k in fields})
-
-
-# ---------------------------------------------------------------------------
-# Mesh construction / ambient mesh
-# ---------------------------------------------------------------------------
-
-_MAKE_MESH_HAS_AXIS_TYPES = ("axis_types"
-                             in inspect.signature(jax.make_mesh).parameters)
-
-
-def auto_axis_types(n: int) -> Optional[tuple]:
-    """(AxisType.Auto,) * n where the enum exists, else None (0.4.x default
-    semantics are already 'auto')."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
-
-
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None) -> "jax.sharding.Mesh":
-    """jax.make_mesh with Auto axis types wherever the arg is supported."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _MAKE_MESH_HAS_AXIS_TYPES:
-        types = auto_axis_types(len(tuple(axis_shapes)))
-        if types is not None:
-            kwargs["axis_types"] = types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Modern JAX spells this jax.set_mesh(mesh); on 0.4.x the Mesh object is
-    its own context manager with the same scoping behaviour.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-# ---------------------------------------------------------------------------
-# Compiled-artifact cost analysis
-# ---------------------------------------------------------------------------
-
-
-def cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() normalized to a flat dict.
-
-    0.4.x returns a single-element list of dicts (one per program), newer
-    versions return the dict directly; either may be None/empty.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map with the modern signature; legacy fallback maps
-    check_vma onto the old check_rep flag (same meaning, renamed)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _legacy
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
-
-
-# ---------------------------------------------------------------------------
-# Buffer donation
-# ---------------------------------------------------------------------------
+    """jax.make_mesh with Auto axis types on every axis."""
+    shape = tuple(axis_shapes)
+    return jax.make_mesh(shape, tuple(axis_names),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def donation_kwargs(*argnums: int) -> dict:

@@ -390,9 +390,10 @@ class BanditPolicy:
         self.transfers.count_d2h()
         theta = np.einsum("mij,mj->mi", a_inv, b)
         self.state = self.state._replace(
-            b=jnp.asarray(b),
-            theta=jnp.asarray(theta.astype(np.float32)),
-            reward_sum=jnp.asarray(np.asarray(reward_sum, np.float32)))
+            b=self._on_state_device(b),
+            theta=self._on_state_device(theta.astype(np.float32)),
+            reward_sum=self._on_state_device(
+                np.asarray(reward_sum, np.float32)))
         self.transfers.count_h2d()
 
     def state_dict(self) -> dict:
@@ -401,4 +402,11 @@ class BanditPolicy:
 
     def load_state_dict(self, d: dict) -> None:
         self.transfers.count_h2d()
-        self.state = BanditState(**{k: jnp.asarray(v) for k, v in d.items()})
+        self.state = BanditState(**{k: self._on_state_device(v)
+                                    for k, v in d.items()})
+
+    def _on_state_device(self, x) -> jax.Array:
+        """``x`` on the device the bandit state lives on: host-side
+        rewrites (fleet all-reduce, λ changes) keep a replica's state on
+        its own chip, whatever the default device is when they run."""
+        return jax.device_put(x, next(iter(self.state.A_inv.devices())))

@@ -15,11 +15,41 @@ a measured-power backend can replace this on hardware with telemetry.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Dict, Optional
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator generation."""
+
+    flops_bf16: float           # FLOP/s
+    hbm_bw: float               # bytes/s
+    hbm_bytes: float            # device memory
+    source: str
+
+
+# keyed by ``jax.Device.device_kind``; a chip that is not here is an error
+# (``chip_peaks``), never a silent default
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peak table entry for ``device_kind``; raises for unknown chips."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(CHIP_PEAKS)}") from None
+
 
 # --- TPU v5e hardware constants (per chip) ---------------------------------
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # bytes/s
+_V5E = CHIP_PEAKS["TPU v5 lite"]
+PEAK_FLOPS_BF16 = _V5E.flops_bf16   # FLOP/s
+HBM_BW = _V5E.hbm_bw                # bytes/s
 ICI_BW_PER_LINK = 50e9          # bytes/s per link (~)
 ICI_LINKS = 4                   # 2D torus: 4 links/chip on v5e
 CHIP_TDP_W = 200.0              # board power envelope

@@ -439,6 +439,35 @@ class GreenServRouter:
                                            np.ndarray, float]:
         """Fused path: one host hashing pass, then ``_fused_decide``."""
         ctx = self.context
+        n = len(queries)
+        args, static, feasible, t0, feat_ms, comp_ms = self._device_inputs(
+            queries, embeddings, task_labels, blocked)
+        ctx.record_device_batch(n, feat_ms, comp_ms)
+        out = _fused_decide(*args, **static)
+        _sync(out)                    # timing boundary: the decision clock
+        (arms_d, masked, labels, clusters, cent2, cnt2, ini2,
+         comp_scores_d, comp_bins_d) = out
+        if ctx.use_cluster:
+            ctx.kmeans.load_device_state(cent2, cnt2, ini2)
+        self.policy.advance_key()     # mirror select_batch's state step
+        comp = [(float(s), int(b)) for s, b in
+                zip(np.asarray(comp_scores_d, np.float32)[:n],
+                    np.asarray(comp_bins_d)[:n])]
+        ctxs = ctx.make_contexts(np.asarray(labels, dtype=np.int64)[:n],
+                                 np.asarray(clusters, dtype=np.int64)[:n],
+                                 comp)
+        return (ctxs, np.asarray(arms_d, dtype=np.int64)[:n],
+                np.asarray(masked, dtype=np.float32)[:n], feasible, t0)
+
+    def _device_inputs(self, queries: Sequence[Query],
+                       embeddings: Optional[np.ndarray],
+                       task_labels: Optional[np.ndarray],
+                       blocked: Optional[np.ndarray] = None) -> tuple:
+        """Host half of the fused path: Flesch counts, the hashing pass and
+        the feasibility matrix, padded to a power of two.  Returns
+        ``(args, static, feasible, t0, featurize_ms, complexity_ms)`` for
+        ``_fused_decide``; ``t0`` starts the decision clock."""
+        ctx = self.context
         texts = [q.text for q in queries]
         n = len(texts)
         tc0 = time.perf_counter()
@@ -474,8 +503,7 @@ class GreenServRouter:
         pad_rows[:, 1] = 1            # sentences >= 1: padding rows never 0/0
         comp_counts = np.concatenate([comp_counts, pad_rows])
         valid = np.arange(q_pad) < n
-        ctx.record_device_batch(n, (time.perf_counter() - tc1) * 1e3,
-                                (tc1 - tc0) * 1e3)
+        feat_ms = (time.perf_counter() - tc1) * 1e3
         t0 = time.perf_counter()
         feasible = self._feasible_matrix(queries, blocked)
         feas_pad = np.zeros((q_pad, self.config.max_arms), bool)
@@ -492,31 +520,27 @@ class GreenServRouter:
             ini = jnp.int32(0)
         w_clf, b_clf = ctx.classifier_params()
         st = self.policy.state
-        out = _fused_decide(
-            jnp.asarray(ids), jnp.asarray(weights), emb_in, labels_in,
-            ctx.embedder.proj_device, w_clf, b_clf, cent, cnt, ini,
-            jnp.asarray(comp_counts), jnp.float32(ctx.complexity.lo),
-            ctx.complexity.bin_width32, jnp.asarray(feas_pad),
-            jnp.asarray(valid), st.A_inv, st.theta, st.active,
-            mode=mode, use_task=ctx.use_task, use_cluster=ctx.use_cluster,
-            use_complexity=ctx.use_complexity,
-            n_tasks=self.config.n_tasks, n_clusters=self.config.n_clusters,
-            n_bins=self.config.n_complexity_bins,
-            alpha=float(self.config.alpha_ucb))
-        _sync(out)                    # timing boundary: the decision clock
-        (arms_d, masked, labels, clusters, cent2, cnt2, ini2,
-         comp_scores_d, comp_bins_d) = out
-        if ctx.use_cluster:
-            ctx.kmeans.load_device_state(cent2, cnt2, ini2)
-        self.policy.advance_key()     # mirror select_batch's state step
-        comp = [(float(s), int(b)) for s, b in
-                zip(np.asarray(comp_scores_d, np.float32)[:n],
-                    np.asarray(comp_bins_d)[:n])]
-        ctxs = ctx.make_contexts(np.asarray(labels, dtype=np.int64)[:n],
-                                 np.asarray(clusters, dtype=np.int64)[:n],
-                                 comp)
-        return (ctxs, np.asarray(arms_d, dtype=np.int64)[:n],
-                np.asarray(masked, dtype=np.float32)[:n], feasible, t0)
+        args = (jnp.asarray(ids), jnp.asarray(weights), emb_in, labels_in,
+                ctx.embedder.proj_device, w_clf, b_clf, cent, cnt, ini,
+                jnp.asarray(comp_counts), jnp.float32(ctx.complexity.lo),
+                ctx.complexity.bin_width32, jnp.asarray(feas_pad),
+                jnp.asarray(valid), st.A_inv, st.theta, st.active)
+        static = dict(mode=mode, use_task=ctx.use_task,
+                      use_cluster=ctx.use_cluster,
+                      use_complexity=ctx.use_complexity,
+                      n_tasks=self.config.n_tasks,
+                      n_clusters=self.config.n_clusters,
+                      n_bins=self.config.n_complexity_bins,
+                      alpha=float(self.config.alpha_ucb))
+        return args, static, feasible, t0, feat_ms, (tc1 - tc0) * 1e3
+
+    def lower_decide(self, queries: Sequence[Query]) -> "jax.stages.Lowered":
+        """The device-path routing program ``route_batch`` would run for
+        ``queries``, lowered and not run — to inspect what the device
+        executes (e.g. that the Pallas kernels are compiled:
+        ``tpu_custom_call`` in its text)."""
+        args, static, _, _, _, _ = self._device_inputs(queries, None, None)
+        return _fused_decide.lower(*args, **static)
 
     def feedback(self, fb: Feedback,
                  oracle_reward: Optional[float] = None) -> float:

@@ -16,5 +16,8 @@ def encode(text: str, add_bos: bool = True) -> List[int]:
 
 
 def decode(ids: List[int]) -> str:
-    return bytes(max(i - _OFFSET, 0) for i in ids
-                 if i >= _OFFSET).decode("utf-8", errors="replace")
+    """Bytes back to text.  Ids outside the byte range (specials, and the
+    rest of a larger model vocabulary) decode to nothing."""
+    return bytes(i - _OFFSET for i in ids
+                 if _OFFSET <= i < VOCAB_SIZE).decode("utf-8",
+                                                      errors="replace")

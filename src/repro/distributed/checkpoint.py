@@ -36,7 +36,7 @@ LATEST = "LATEST"
 
 
 def _flatten(tree: Any) -> Tuple[List[Tuple[str, Any]], Any]:
-    leaves, treedef = compat.tree_flatten_with_path(tree)
+    leaves, treedef = jax.tree.flatten_with_path(tree)
     return [(compat.path_str(path), leaf) for path, leaf in leaves], treedef
 
 

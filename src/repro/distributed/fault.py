@@ -58,6 +58,9 @@ class HeartbeatMonitor:
     def beat(self, name: str) -> None:
         self._beats[name].beat()
 
+    def last_beat(self, name: str) -> float:
+        return self._beats[name].last_beat
+
     def stale(self) -> List[str]:
         return [n for n, hb in self._beats.items()
                 if hb.stale(self.timeout_s)]

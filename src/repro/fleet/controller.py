@@ -36,6 +36,7 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.distributed.fault import HeartbeatMonitor
@@ -54,13 +55,19 @@ def _arrayify(tree):
 
 
 class FleetShard:
-    """One pool replica: spec + server + liveness + harvest bookkeeping."""
+    """One pool replica: spec + server + liveness + harvest bookkeeping.
 
-    def __init__(self, spec: ShardSpec, server: PoolServer, mesh=None):
+    ``device`` is the shard's own device: the controller runs the shard's
+    work under ``placed()``, so the arrays its router and server create
+    live there (engines commit their params and cache to it themselves)."""
+
+    def __init__(self, spec: ShardSpec, server: PoolServer, mesh=None,
+                 device: Optional[jax.Device] = None):
         self.spec = spec
         self.name = spec.name
         self.server = server
         self.mesh = mesh
+        self.device = device
         self.alive = True
         # uids whose responses the controller has already read out of
         # server.responses (which is never popped — see module docstring)
@@ -69,6 +76,10 @@ class FleetShard:
     @property
     def load(self) -> int:
         return len(self.server.arrivals) + len(self.server.inflight)
+
+    def placed(self):
+        """Context making the shard's device the default device."""
+        return jax.default_device(self.device)
 
 
 class FleetController:
@@ -154,12 +165,19 @@ class FleetController:
         stale, run the periodic stat sync.  Returns fresh responses."""
         self._steps += 1
         done: List = []
+        tick_start = self.clock()
         for shard in self.live_shards():
-            shard.server.step()
+            with shard.placed():
+                shard.server.step()
             self.monitor.beat(shard.name)
             done.extend(self._harvest(shard))
+        # a shard that beat during this tick completed a step, however long
+        # it took (shards step one after another, so one shard's compile
+        # ages every other heartbeat): only a shard that missed the tick
+        # can be dead
         for name in self.monitor.stale():
-            self._fail_over(self.shards[name])
+            if self.monitor.last_beat(name) < tick_start:
+                self._fail_over(self.shards[name])
         if self.sync_every and self._steps % self.sync_every == 0 \
                 and len(self._sync_targets()) > 1:
             self.sync_now()
@@ -234,9 +252,10 @@ class FleetController:
                 if new_name in target.server.engines:
                     continue   # chained failure already adopted this base
                 new_profile = dataclasses.replace(profile, name=new_name)
-                target.server.add_engine(
-                    new_profile,
-                    self.engine_factory(new_profile, target.spec))
+                with target.placed():
+                    target.server.add_engine(
+                        new_profile,
+                        self.engine_factory(new_profile, target.spec))
                 adopted += 1
         self.stats["adopted_engines"] += adopted
         remesh = self._remesh_record(dead)
@@ -360,19 +379,24 @@ def build_fleet(plan: FleetPlan,
                 server_kwargs: Optional[dict] = None) -> FleetController:
     """Wire a ``FleetController`` from a plan: one router replica + one
     ``PoolServer`` per shard, engines from ``engine_factory(profile,
-    spec)``.  ``build_meshes=True`` additionally materializes per-shard
-    and fleet meshes (requires the plan's device ids to be live and
+    spec)``, all built with the shard's first device as the default device
+    (``plan.shard_device``).  Factories that build real engines should
+    also pass that device to ``ModelEngine(device=...)``.
+    ``build_meshes=True`` additionally materializes per-shard and fleet
+    meshes (requires the plan's device ids to be live and
     disjoint — skip on a shared-device CPU fleet)."""
     shards = []
     for spec in plan.shards:
-        router = router_factory(spec)
-        engines = {p.name: engine_factory(p, spec)
-                   for p in [router.pool[i]
-                             for i in range(len(router.pool))]}
-        server = PoolServer(router, engines, clock=clock,
-                            **(server_kwargs or {}))
+        device = plan.shard_device(spec)
+        with jax.default_device(device):
+            router = router_factory(spec)
+            engines = {p.name: engine_factory(p, spec)
+                       for p in [router.pool[i]
+                                 for i in range(len(router.pool))]}
+            server = PoolServer(router, engines, clock=clock,
+                                **(server_kwargs or {}))
         mesh = plan.shard_mesh(spec) if build_meshes else None
-        shards.append(FleetShard(spec, server, mesh=mesh))
+        shards.append(FleetShard(spec, server, mesh=mesh, device=device))
     fleet_mesh = plan.fleet_mesh() if build_meshes else None
     return FleetController(shards, sync_every=sync_every,
                            heartbeat_timeout_s=heartbeat_timeout_s,

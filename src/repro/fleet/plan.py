@@ -61,6 +61,10 @@ class FleetPlan:
         import jax
         return {d.id: d for d in jax.devices()}
 
+    def shard_device(self, spec: ShardSpec):
+        """The shard's first device — where a one-chip replica lives."""
+        return self._devices_by_id()[spec.device_ids[0]]
+
     def shard_mesh(self, spec: ShardSpec):
         """Per-shard mesh: (n_dev, 1) over exactly the shard's devices."""
         from jax.sharding import Mesh

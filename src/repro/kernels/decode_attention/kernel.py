@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -2.3819763e38
 LANES = 128
@@ -102,7 +101,7 @@ def flash_decode_fwd(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((group, LANES), jnp.float32),
             pltpu.VMEM((group, LANES), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(scalars, q4, k_cache, v_cache)

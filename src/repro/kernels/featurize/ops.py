@@ -33,7 +33,7 @@ def _embed_jit(ids, weights, proj, bq: int, lb: int, interpret: bool):
 
 
 def hashed_embed(ids: jax.Array, weights: jax.Array, proj: jax.Array,
-                 block_q: int = 8, block_l: int = 64,
+                 block_q: int = 8, block_l: int = 128,
                  interpret: Optional[bool] = None) -> jax.Array:
     """ids/weights: (Q, L), id −1 = padding; proj: (hash_dim, dim) →
     (Q, dim) unit embeddings.  Pads Q and L to powers of two (L floored at

@@ -17,19 +17,17 @@ def _pick_block(n: int, target: int) -> int:
     return b
 
 
-# static alpha/block/interpret: one compiled program per (M, d, Q, blocks)
+# static alpha/block/interpret: one compiled program per (M, d, Q, block)
 # combination — serving batch sizes recur, so steady state is cache hits,
 # not per-call retracing of the pallas_call
-@functools.partial(jax.jit, static_argnames=("alpha", "bm", "bq",
-                                             "interpret"))
-def _scores_jit(a_inv, theta, xq, alpha: float, bm: int, bq: int,
-                interpret: bool):
-    return linucb_scores_fwd(a_inv, theta, xq, alpha, bm=bm, bq=bq,
+@functools.partial(jax.jit, static_argnames=("alpha", "bq", "interpret"))
+def _scores_jit(a_inv, theta, xq, alpha: float, bq: int, interpret: bool):
+    return linucb_scores_fwd(a_inv, theta, xq, alpha, bq=bq,
                              interpret=interpret)
 
 
 def linucb_scores(a_inv: jax.Array, theta: jax.Array, x: jax.Array,
-                  alpha: float, block_m: int = 16, block_q: int = 128,
+                  alpha: float, block_q: int = 128,
                   interpret: Optional[bool] = None) -> jax.Array:
     """a_inv: (M, d, d); theta: (M, d); x: (d,) or (Q, d) → (M,) or (Q, M)."""
     if interpret is None:
@@ -44,10 +42,9 @@ def linucb_scores(a_inv: jax.Array, theta: jax.Array, x: jax.Array,
     if q_pad != q:
         xq = jnp.concatenate(
             [xq, jnp.zeros((q_pad - q, xq.shape[1]), xq.dtype)])
-    bm = _pick_block(a_inv.shape[0], block_m)
     bq = _pick_block(q_pad, block_q)
     out = _scores_jit(a_inv.astype(jnp.float32),
                       theta.astype(jnp.float32),
                       xq.astype(jnp.float32), float(alpha),
-                      bm=bm, bq=bq, interpret=bool(interpret))[:q]
+                      bq=bq, interpret=bool(interpret))[:q]
     return out[0] if single else out

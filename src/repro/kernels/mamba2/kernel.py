@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, hout_ref,
@@ -92,7 +91,7 @@ def ssd_fwd(x, dt, B, C, A, h0, chunk: int, interpret: bool):
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct((b, h, p, n), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, B, C, A.reshape(-1, 1), h0)

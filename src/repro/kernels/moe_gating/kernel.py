@@ -15,7 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro import compat
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -52,7 +52,7 @@ def topk_gating_fwd(logits: jax.Array, k: int, bt: int,
                    pl.BlockSpec((bt, k), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((t, k), jnp.float32),
                    jax.ShapeDtypeStruct((t, k), jnp.int32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(logits)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
@@ -87,7 +86,7 @@ def wkv_fwd(r, k, v, logw, u, s0, chunk: int, interpret: bool):
         out_shape=[jax.ShapeDtypeStruct(r.shape, r.dtype),
                    jax.ShapeDtypeStruct((b, h, kd, kd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((kd, kd), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u, s0)

@@ -28,7 +28,6 @@ from typing import Any, Dict, Optional
 
 import jax
 
-from repro import compat
 from repro.configs import (ARCH_IDS, SHAPES, cell_applicable, for_mode,
                            get_config, input_specs)
 from repro.core import energy as energy_lib
@@ -86,7 +85,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
 
     fn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                  out_shardings=bundle.out_shardings, donate_argnums=donate)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
     meta = {"cfg": cfg, "mesh": mesh, "cell": cell, "bundle": bundle}
     return lowered, meta
@@ -115,7 +114,7 @@ def analyse(lowered, meta, compile_it: bool = True) -> Dict[str, Any]:
                 mem.output_size_in_bytes - mem.alias_size_in_bytes)
         rec["peak_bytes_per_dev"] = int(peak)
         rec["fits_hbm"] = bool(peak <= HBM_PER_CHIP)
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         # raw XLA numbers (NOT trip-count-aware — kept for cross-checking)
         rec["xla_flops_per_dev"] = float(ca.get("flops", 0.0))
         rec["xla_bytes_per_dev"] = float(ca.get("bytes accessed", 0.0))

@@ -5,8 +5,7 @@ state — smoke tests and benches must keep seeing 1 CPU device; only
 ``dryrun.py`` (which sets XLA_FLAGS before any jax import) sees 512.
 
 All meshes are built through ``repro.compat.make_mesh``, which requests
-Auto axis types on JAX versions that have the AxisType enum and omits the
-argument on 0.4.x (where auto is the only behaviour).
+Auto axis types (JAX's own default is Explicit).
 """
 from __future__ import annotations
 

@@ -1,15 +1,21 @@
-"""Serving launcher: GreenServ pool server over real reduced-config models.
+"""Serving launcher: GreenServ pool server over real JAX models.
 
-Builds a heterogeneous pool of small-but-real JAX models (one per requested
-arch family), the GreenServ router with all three context features, the
-GreenCache reuse layer (``--cache-mode``, default prefix-KV reuse), and the
-continuous-batching scheduler; then drives a synthetic query stream through
-it with hedging and fault injection available as flags.
+Builds a heterogeneous pool of real JAX models (one per requested arch
+family; reduced configs by default, published widths with
+``--published-widths``), the GreenServ router with all three context
+features, the GreenCache reuse layer (``--cache-mode``, default prefix-KV
+reuse), and the continuous-batching scheduler; then drives a synthetic
+query stream through it with hedging and fault injection available as
+flags.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.serve --queries 60 \
         --pool granite-3-8b rwkv6-1.6b qwen2-moe-a2.7b --hedge 40 \
         --prefill-chunk 8 --cache-mode full --semantic-threshold 0.92
+
+    # published widths on one accelerator (weights in bf16)
+    PYTHONPATH=src python -m repro.launch.serve --published-widths \
+        --pool h2o-danube-3-4b rwkv6-1.6b --max-len 1024 --queries 8
 """
 from __future__ import annotations
 
@@ -28,14 +34,22 @@ from repro.core.router import GreenServRouter
 from repro.core.types import ModelProfile, Query, RouterConfig
 from repro.data import stream as stream_lib
 from repro.data import tokenizer as tok
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import ModelEngine, PoolServer
 from repro.telemetry import EnergyBudgetGovernor, Telemetry, dump_jsonl
 
 
 def build_real_pool(arch_ids: List[str], max_batch: int = 4,
                     max_len: int = 192, seed: int = 0,
-                    prefill_chunk: int = 8, disaggregate: bool = False):
-    """Reduced-config real engines + matching pool profiles.
+                    prefill_chunk: int = 8, disaggregate: bool = False,
+                    smoke: bool = True):
+    """Real engines + matching pool profiles.
+
+    ``smoke`` (default) builds the reduced same-family configs with the
+    byte tokenizer's vocabulary; ``smoke=False`` builds each arch at its
+    published widths, depth and vocabulary (the byte tokenizer's ids fit
+    inside every published vocabulary).  Weights are stored in bf16 either
+    way (``ModelEngine``).
 
     ``prefill_chunk`` (prompt tokens per engine prefill tick, default 8 —
     recorded in ROADMAP conventions) cuts TTFT roughly by the chunk factor
@@ -51,8 +65,8 @@ def build_real_pool(arch_ids: List[str], max_batch: int = 4,
     decode_engines: Dict[str, ModelEngine] = {}
     profiles: List[ModelProfile] = []
     for i, arch in enumerate(arch_ids):
-        cfg = get_config(arch, smoke=True,
-                         vocab_size=tok.VOCAB_SIZE, max_seq_len=max_len)
+        cfg = (get_config(arch, smoke=True, vocab_size=tok.VOCAB_SIZE,
+                          max_seq_len=max_len) if smoke else get_config(arch))
         eng = ModelEngine(arch, cfg, jax.random.PRNGKey(seed + i),
                           max_batch=max_batch, max_len=max_len,
                           detokenize=tok.decode, prefill_chunk=prefill_chunk)
@@ -83,6 +97,11 @@ def main() -> None:
                                                   "qwen2-moe-a2.7b"],
                     choices=ARCH_IDS)
     ap.add_argument("--queries", type=int, default=40)
+    ap.add_argument("--published-widths", action="store_true",
+                    help="serve each arch at its published widths, depth "
+                         "and vocabulary (default: reduced smoke configs)")
+    ap.add_argument("--max-len", type=int, default=192,
+                    help="per-slot cache depth in tokens")
     ap.add_argument("--lam", type=float, default=0.4)
     ap.add_argument("--hedge", type=int, default=None,
                     help="hedge after N scheduler steps in queue")
@@ -137,9 +156,10 @@ def main() -> None:
                          "full-depth KV cache stay unified)")
     args = ap.parse_args()
 
+    print(f"[serve] compilation cache: {enable_compile_cache()}")
     engines, pool, decode_engines = build_real_pool(
-        args.pool, prefill_chunk=args.prefill_chunk,
-        disaggregate=args.disaggregate)
+        args.pool, max_len=args.max_len, prefill_chunk=args.prefill_chunk,
+        disaggregate=args.disaggregate, smoke=not args.published_widths)
     config = RouterConfig(lam=args.lam, energy_scale_wh=0.05,
                           featurize=args.featurize)
     router = GreenServRouter(config, pool)

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import ARCH_IDS, get_config
 from repro.distributed import checkpoint as ckpt
 from repro.distributed.elastic import build_mesh, plan_remesh
@@ -88,7 +87,7 @@ def train(arch: str, smoke: bool, steps: int, batch: int, seq: int,
     straggle = StragglerDetector()
     losses = []
     step = start_step
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         while step < steps:
             t0 = time.monotonic()
             batch_data = next(data)

@@ -10,6 +10,7 @@ One surface for the training loop, the serving engine, and the dry-run:
     cache_shapes / init_cache             decode cache pytrees
     serve_step(params, token, cache, cfg) one-token decode
     prefill_chunk(params, toks, cache, …) C-token prompt slab into the cache
+    reset_slot(cache, slot)               zero one slot before reuse
     splice_prefix(cache, slot, k, v)      reused prompt-prefix KV into a slot
     supports_chunked_prefill(cfg)         which layouts take the chunked path
 
@@ -166,6 +167,19 @@ def supports_chunked_prefill(cfg: ModelConfig) -> bool:
     cache for a ``k`` entry to exclude ring layouts).
     """
     return cfg.layout in ("dense", "moe", "encdec")
+
+
+def reset_slot(cache: Params, slot) -> Params:
+    """Zero one decode slot: its length and its row of every state leaf.
+
+    Every cache leaf is laid out ``(layers, batch, ...)`` except
+    ``length`` ``(batch,)``.  Positional KV beyond a slot's length is
+    masked anyway, but recurrent state (rwkv ``wkv``/shifts, mamba
+    ``conv``/``ssm``) is read unconditionally — a reused slot must not
+    inherit the previous request's state."""
+    return {name: (leaf.at[slot].set(0) if name == "length"
+                   else leaf.at[:, slot].set(0))
+            for name, leaf in cache.items()}
 
 
 def splice_prefix(cache: Params, slot: int, k_block, v_block) -> Params:

@@ -8,6 +8,7 @@ passes the shape tree itself (no allocation), which is what lets us lower
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -29,32 +30,70 @@ def sds(shape, dtype) -> jax.ShapeDtypeStruct:
 # ---------------------------------------------------------------------------
 
 
-def _init_leaf(key, path: str, s: jax.ShapeDtypeStruct) -> jax.Array:
-    name = path.split("/")[-1]
+def _draw(key, name: str, shape: Tuple[int, ...], dtype,
+          fan_in: int) -> jax.Array:
     if name.startswith(("norm", "scale", "ln")):
-        return jnp.ones(s.shape, s.dtype)
+        return jnp.ones(shape, dtype)
     if name.startswith(("bias", "dt_bias")):
-        return jnp.zeros(s.shape, s.dtype)
+        return jnp.zeros(shape, dtype)
     if name.startswith("a_log"):  # mamba A init: log of [1, 16)
-        u = jax.random.uniform(key, s.shape, jnp.float32, 1.0, 16.0)
-        return jnp.log(u).astype(s.dtype)
+        u = jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
+        return jnp.log(u).astype(dtype)
     if name.startswith("decay"):  # rwkv decay speed init
-        return jax.random.uniform(key, s.shape, jnp.float32, -8.0, -4.0).astype(s.dtype)
+        return jax.random.uniform(key, shape, jnp.float32, -8.0,
+                                  -4.0).astype(dtype)
     if name.startswith("embed"):
-        return (jax.random.normal(key, s.shape, jnp.float32) * 0.02).astype(s.dtype)
-    fan_in = s.shape[-2] if len(s.shape) >= 2 else max(s.shape[-1], 1)
+        return (jax.random.normal(key, shape, jnp.float32)
+                * 0.02).astype(dtype)
     std = 1.0 / math.sqrt(fan_in)
-    return (jax.random.normal(key, s.shape, jnp.float32) * std).astype(s.dtype)
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+# One compiled program per (leaf kind, shape, dtype, stacked).  The
+# generator's float32 values exist only inside the program, and a stacked
+# per-layer leaf is drawn one layer at a time, so they never span a whole
+# stack: a danube MLP leaf would otherwise need 3.8 GB of float32 (and as
+# much again in random bits) next to its 1.9 GB bf16 result.
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _init_leaf(key, name: str, shape: Tuple[int, ...], dtype, fan_in: int,
+               stacked: bool) -> jax.Array:
+    if not stacked:
+        return _draw(key, name, shape, dtype, fan_in)
+    return jax.lax.map(lambda k: _draw(k, name, shape[1:], dtype, fan_in),
+                       jax.random.split(key, shape[0]))
+
+
+def _fan_in(path: str, shape: Tuple[int, ...]) -> int:
+    """How many inputs each output of a weight leaf sums over.  Attention
+    projections keep heads as axes of their own — ``wq``/``wk``/``wv``
+    are ``(…, d, H, hd)`` and sum over d, ``wo`` is ``(…, H, hd, d)`` and
+    sums over H·hd; every other matrix is ``(…, d_in, d_out)``.  (Reading
+    ``shape[-2]`` off an attention leaf gave q and k a std of ~1/sqrt(H)
+    instead of 1/sqrt(d): saturated softmax, and random-weight logits that
+    a bf16 rounding difference could flip.)"""
+    *parents, name = path.split("/")
+    if parents and parents[-1].endswith("attn") and len(shape) >= 3:
+        if name in ("wq", "wk", "wv"):
+            return shape[-3]
+        if name == "wo":
+            return shape[-3] * shape[-2]
+    return shape[-2] if len(shape) >= 2 else max(shape[-1], 1)
 
 
 def materialize(key: jax.Array, shape_tree: Params) -> Params:
     """Initialize a params pytree from its ShapeDtypeStruct tree."""
-    leaves, treedef = compat.tree_flatten_with_path(shape_tree)
+    leaves, treedef = jax.tree.flatten_with_path(shape_tree)
     keys = jax.random.split(key, len(leaves))
     out = []
     for k, (path, s) in zip(keys, leaves):
-        out.append(_init_leaf(k, compat.path_str(path), s))
-    return jax.tree.unflatten(jax.tree.structure(shape_tree), out)
+        path = compat.path_str(path)
+        shape = tuple(s.shape)
+        # "layers", "enc_layers", "dec_layers": leading axis is the layer
+        stacked = path.split("/")[0].endswith("layers") and len(shape) >= 2
+        out.append(_init_leaf(k, path.split("/")[-1], shape,
+                              jnp.dtype(s.dtype), _fan_in(path, shape),
+                              stacked))
+    return jax.tree.unflatten(treedef, out)
 
 
 def param_count(tree: Params) -> int:

@@ -26,6 +26,7 @@ telemetry layer tags and charges the two separately).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Dict, List, Optional
 
@@ -33,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.configs import for_mode
 from repro.core.energy import (CostModelParams, EnergyMonitor, JOULES_PER_WH,
                                chunk_rider_cost, decode_step_cost,
                                energy_joules, kv_migration_cost,
@@ -45,6 +47,37 @@ from repro.serving.request import Request, RequestState, Response
 
 class EngineFailure(RuntimeError):
     pass
+
+
+# admission-time slot reset, in place (the cache is donated)
+_reset_slot = jax.jit(api.reset_slot, donate_argnums=(0,))
+
+
+# The engine's two tick programs, one compiled program per (config, shape):
+# engines of one model share them, and they can be lowered from shapes
+# alone (tests/test_tpu_compile.py compiles them for a described TPU).
+@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
+def greedy_step(params, cache, tokens, cfg: ModelConfig):
+    """One-token tick over every slot: (greedy next token (B,), cache)."""
+    logits, cache = api.serve_step(params, tokens, cache, cfg)
+    return jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), cache
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
+def greedy_chunk_step(params, cache, tokens, n_active, cfg: ModelConfig):
+    """Chunked-prefill tick: (B, C) slab, ``n_active`` real tokens per
+    slot; the next token per slot comes from its last active position."""
+    logits, cache = api.prefill_chunk(params, tokens, cache, cfg, n_active)
+    idx = jnp.maximum(n_active - 1, 0)
+    last = jnp.take_along_axis(logits, idx[:, None, None], axis=1)
+    return jnp.argmax(last[:, 0], axis=-1).astype(jnp.int32), cache
+
+
+def engine_profile(name: str, cfg: ModelConfig) -> ModelProfile:
+    """The pool profile of a ``ModelEngine`` serving ``cfg`` — what a
+    router replica is built from before its engines exist (fleet shards)."""
+    return ModelProfile(name=name, family=cfg.layout,
+                        params_b=cfg.param_count() / 1e9, arch_config=cfg)
 
 
 class BaseEngine:
@@ -162,19 +195,28 @@ class ModelEngine(BaseEngine):
     KV cache (``api.supports_chunked_prefill`` + a ``k`` cache entry —
     ring-buffer windowed caches are excluded); everything else silently
     clamps to 1.
+
+    Weights are stored in bf16 (``configs.for_mode(cfg, "serve")``).  With
+    ``device`` set, params and cache are committed to that device, so every
+    tick runs there (a fleet shard's replica on its own chip).
     """
 
     def __init__(self, name: str, cfg: ModelConfig, key: jax.Array,
                  max_batch: int = 4, max_len: int = 256,
                  params=None, detokenize: Optional[Callable] = None,
-                 prefill_chunk: int = 1, role: str = "unified"):
+                 prefill_chunk: int = 1, role: str = "unified",
+                 device: Optional[jax.Device] = None):
         self.name = name
-        self.cfg = dataclasses.replace(cfg, kv_update="where")
+        self.cfg = dataclasses.replace(for_mode(cfg, "serve"),
+                                       kv_update="where")
         self.max_batch = max_batch
         self.max_len = max_len
-        self.params = params if params is not None else api.init_params(
-            self.cfg, key)
-        self.cache = api.init_cache(self.cfg, max_batch, max_len)
+        self.device = device
+        if params is None:
+            with jax.default_device(device):
+                params = api.init_params(self.cfg, key)
+        self.params = self._place(params)
+        self.cache = self._new_cache()
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.queue: List[Request] = []
         self.detokenize = detokenize or (lambda toks: "")
@@ -188,16 +230,11 @@ class ModelEngine(BaseEngine):
             n_active_params=float(cfg.active_param_count()),
             d_model=cfg.d_model, n_layers=cfg.n_layers,
             kv_heads=max(cfg.n_kv_heads, 1), head_dim=cfg.head_dim)
-        self.profile = ModelProfile(
-            name=name, family=cfg.layout,
-            params_b=cfg.param_count() / 1e9, arch_config=cfg)
+        self.profile = engine_profile(name, cfg)
         self.n_steps = 0
+        self.n_chunk_steps = 0
 
-        def _step(params, cache, tokens):
-            logits, cache = api.serve_step(params, tokens, cache, self.cfg)
-            return jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), cache
-
-        self._jit_step = jax.jit(_step, donate_argnums=(1,))
+        self._jit_step = functools.partial(greedy_step, cfg=self.cfg)
         self._jit_chunk_step = None
         self.prefill_chunk = 1
         self.set_prefill_chunk(prefill_chunk)
@@ -214,6 +251,35 @@ class ModelEngine(BaseEngine):
         self._migration_joules = 0.0
         self._modeled_time_s = 0.0
         self.set_role(role)
+
+    def _place(self, tree):
+        """Commit ``tree`` to the engine's device (no-op without one)."""
+        return tree if self.device is None else jax.device_put(tree,
+                                                               self.device)
+
+    def _new_cache(self):
+        with jax.default_device(self.device):
+            return self._place(api.init_cache(self.cfg, self.max_batch,
+                                              self.max_len))
+
+    def warmup(self) -> float:
+        """Compile every tick program before the engine takes traffic;
+        returns the seconds it took.  Each jitted step runs once over idle
+        slots, then the cache is rebuilt, so no state survives."""
+        t0 = time.perf_counter()
+        cache = _reset_slot(self.cache, 0)
+        tok, cache = self._jit_step(
+            self.params, cache, jnp.zeros((self.max_batch, 1), jnp.int32))
+        if self._jit_chunk_step is not None:
+            tok, cache = self._jit_chunk_step(
+                self.params, cache,
+                jnp.zeros((self.max_batch, self.prefill_chunk), jnp.int32),
+                jnp.zeros((self.max_batch,), jnp.int32))
+        jax.block_until_ready(tok)
+        del cache
+        self.cache = self._new_cache()
+        self._last_step_s = time.monotonic()
+        return time.perf_counter() - t0
 
     def set_role(self, role: str) -> None:
         """Pin the engine to one serving phase.  The same full-depth
@@ -238,19 +304,9 @@ class ModelEngine(BaseEngine):
         if n == self.prefill_chunk:
             return      # keep the warmed jit cache (hot-add re-push path)
         self.prefill_chunk = n
-        if n == 1:
-            self._jit_chunk_step = None
-            return
-
-        def _chunk_step(params, cache, tokens, n_active):
-            logits, cache = api.prefill_chunk(params, tokens, cache,
-                                              self.cfg, n_active)
-            # next token per slot from its last *active* position's logits
-            idx = jnp.maximum(n_active - 1, 0)
-            last = jnp.take_along_axis(logits, idx[:, None, None], axis=1)
-            return jnp.argmax(last[:, 0], axis=-1).astype(jnp.int32), cache
-
-        self._jit_chunk_step = jax.jit(_chunk_step, donate_argnums=(1,))
+        self._jit_chunk_step = (
+            None if n == 1 else functools.partial(greedy_chunk_step,
+                                                  cfg=self.cfg))
 
     def set_prefix_cache(self, cache) -> None:
         """Attach (or detach, with None) a prefix-KV cache.  Only layouts
@@ -332,8 +388,9 @@ class ModelEngine(BaseEngine):
                     continue
                 req.slot = i
                 self.slots[i] = req
-                # reset the slot's cache length so it starts fresh
-                self.cache["length"] = self.cache["length"].at[i].set(0)
+                # zero the slot (length and recurrent state) so the
+                # request starts fresh
+                self.cache = _reset_slot(self.cache, i)
                 if req.kv_payload is not None:
                     self._splice_migration(i, req)
                     continue
@@ -394,8 +451,13 @@ class ModelEngine(BaseEngine):
             req is not None and not req.defunct
             and not req.prefill_done for req in self.slots)
         if self._jit_chunk_step is not None and need_prefill:
-            return self._chunk_tick()
-        return self._decode_tick()
+            out = self._chunk_tick()
+        else:
+            out = self._decode_tick()
+        # the heartbeat is the end of the last completed tick: a tick that
+        # spent a minute compiling still counts as progress
+        self._last_step_s = time.monotonic()
+        return out
 
     def _decode_tick(self) -> List[Response]:
         """Legacy one-token tick: every live slot feeds one token (next
@@ -458,6 +520,7 @@ class ModelEngine(BaseEngine):
             jnp.asarray(n_active))
         next_tok = np.asarray(next_tok)
         self.n_steps += 1
+        self.n_chunk_steps += 1
         self._meter_step(meter)
         return self._advance_slots(next_tok, fed_prompt)
 
@@ -696,7 +759,7 @@ class ModelEngine(BaseEngine):
         self.slots = [None] * self.max_batch
         self.queue = []
         self._migration_outbox = []
-        self.cache = api.init_cache(self.cfg, self.max_batch, self.max_len)
+        self.cache = self._new_cache()
         self._failed = False
         return inflight
 

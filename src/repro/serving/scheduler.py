@@ -91,6 +91,9 @@ class PoolServer:
                                                      for c in text[:32]])
         self.hedge_after_steps = hedge_after_steps
         self.heartbeat_timeout_s = heartbeat_timeout_s
+        # start of the previous health check (None before the first step):
+        # an engine stamped since then completed a tick in between
+        self._prev_check_s: Optional[float] = None
         # injectable time source (same pattern as SemanticCache.clock):
         # virtual-clock benches pass the clock their SimEngines share, so
         # submit/heartbeat timestamps never mix wall and modeled time
@@ -578,16 +581,26 @@ class PoolServer:
 
     # -- fault tolerance -------------------------------------------------------------
 
+    def _stalled(self, eng: BaseEngine, now: float) -> bool:
+        """An engine is stalled when it completed no tick during the last
+        scheduler step *and* its last tick is older than the timeout.
+        Engines step one after another, so a long tick elsewhere in the
+        pool (a first-call compile) ages every heartbeat; only an engine
+        that was given a tick and made no progress can be stalled."""
+        prev = self._prev_check_s
+        hb = eng.heartbeat()
+        return (prev is not None and hb < prev
+                and now - hb > self.heartbeat_timeout_s)
+
     def _check_engines(self) -> None:
         now = self.clock()
         for name, eng in self.engines.items():
-            stalled = now - eng.heartbeat() > self.heartbeat_timeout_s
-            if stalled or getattr(eng, "_failed", False):
+            if self._stalled(eng, now) or getattr(eng, "_failed", False):
                 self._restart_engine(name)
         for name, twin in self.decode_engines.items():
-            stalled = now - twin.heartbeat() > self.heartbeat_timeout_s
-            if stalled or getattr(twin, "_failed", False):
+            if self._stalled(twin, now) or getattr(twin, "_failed", False):
                 self._restart_engine(name, decode=True)
+        self._prev_check_s = now
 
     def _restart_engine(self, name: str, decode: bool = False) -> None:
         eng = self.decode_engines[name] if decode else self.engines[name]

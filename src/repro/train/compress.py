@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.models.layers import Params
 
 INT8_MAX = 127.0
@@ -93,6 +92,6 @@ def compressed_psum(stacked: Params, axis: str, mesh: Mesh) -> Params:
     def body(t):
         return jax.tree.map(lambda g: _psum_int8_leaf(g[0], axis), t)
 
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(in_spec,),
-                          out_specs=out_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(in_spec,),
+                       out_specs=out_spec, check_vma=False)
     return fn(stacked)

@@ -1,22 +1,16 @@
-"""The version-dispatch layer itself: every shim must work on whatever JAX
-this environment ships (0.4.x floor and 0.5+/0.6+ alike)."""
+"""The small JAX helpers in ``repro.compat``, and the compiler-parameter
+contract the Pallas kernels rely on."""
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro import compat
 
 
-def test_jax_version_tuple():
-    assert len(compat.JAX_VERSION) >= 2
-    assert all(isinstance(p, int) for p in compat.JAX_VERSION)
-
-
 def test_tree_flatten_with_path_and_path_str():
     tree = {"outer": {"inner": jnp.ones(2)}, "leaf": jnp.zeros(3),
             "seq": [jnp.ones(1), jnp.ones(4)]}
-    leaves, treedef = compat.tree_flatten_with_path(tree)
+    leaves, treedef = jax.tree.flatten_with_path(tree)
     names = {compat.path_str(path) for path, _ in leaves}
     assert names == {"outer/inner", "leaf", "seq/0", "seq/1"}
     rebuilt = jax.tree_util.tree_unflatten(treedef,
@@ -24,17 +18,15 @@ def test_tree_flatten_with_path_and_path_str():
     assert jax.tree.structure(rebuilt) == jax.tree.structure(tree)
 
 
-def test_tpu_compiler_params_roundtrip():
-    params = compat.tpu_compiler_params(
-        dimension_semantics=("parallel", "arbitrary"))
-    assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
-
-
-def test_tpu_compiler_params_drops_unknown_fields():
-    params = compat.tpu_compiler_params(
-        dimension_semantics=("parallel",),
-        some_flag_from_the_future=True)
+def test_tpu_compiler_params_rejects_unknown_fields():
+    """Kernels build ``pltpu.CompilerParams`` directly: a misspelled field
+    is an error, never silently dropped."""
+    from jax.experimental.pallas import tpu as pltpu
+    params = pltpu.CompilerParams(dimension_semantics=("parallel",))
     assert tuple(params.dimension_semantics) == ("parallel",)
+    with pytest.raises(TypeError):
+        pltpu.CompilerParams(dimension_semantics=("parallel",),
+                             some_flag_from_the_future=True)
 
 
 def test_make_mesh_and_set_mesh():
@@ -42,30 +34,18 @@ def test_make_mesh_and_set_mesh():
     mesh = compat.make_mesh((n, 1), ("data", "model"))
     assert mesh.shape["data"] == n
     assert mesh.shape["model"] == 1
-    with compat.set_mesh(mesh):
-        pass                     # usable as a context manager on every version
+    with jax.set_mesh(mesh):
+        pass
 
 
 def test_auto_axis_types_shape():
-    types = compat.auto_axis_types(3)
-    assert types is None or len(types) == 3
+    mesh = compat.make_mesh((1, 1, 1), ("a", "b", "c"))
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 3
 
 
-def test_cost_analysis_returns_dict():
-    comp = jax.jit(lambda x: x @ x).lower(
-        jax.ShapeDtypeStruct((8, 8), jnp.float32)).compile()
-    ca = compat.cost_analysis(comp)
-    assert isinstance(ca, dict)
-    assert ca.get("flops", 0) > 0
-
-
-def test_shard_map_runs_on_host_mesh():
-    from jax.sharding import PartitionSpec as P
-    n = len(jax.devices())
-    mesh = compat.make_mesh((n,), ("x",))
-    fn = compat.shard_map(
-        lambda a: jax.lax.psum(a, "x"), mesh=mesh,
-        in_specs=(P("x"),), out_specs=P(), check_vma=False)
-    out = fn(jnp.arange(n, dtype=jnp.float32))
-    np.testing.assert_allclose(np.asarray(out),
-                               np.arange(n, dtype=np.float32).sum())
+def test_donation_kwargs_only_where_implemented():
+    kw = compat.donation_kwargs(0, 2)
+    if jax.default_backend() == "cpu":
+        assert kw == {}
+    else:
+        assert kw == {"donate_argnums": (0, 2)}

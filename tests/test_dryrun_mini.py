@@ -20,7 +20,6 @@ def test_mini_dryrun_train_and_decode():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys; sys.path.insert(0, "src")
         import jax
-        from repro import compat
         from repro.configs import get_config, input_specs, ShapeCell
         from repro.launch.mesh import make_mesh
         from repro.launch.hlo_walker import module_cost
@@ -35,7 +34,7 @@ def test_mini_dryrun_train_and_decode():
         bundle = make_train_step(cfg, mesh, batch, n_micro=2, loss_chunk=64)
         fn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                      out_shardings=bundle.out_shardings)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(api.param_shapes(cfg), opt_state_shapes(cfg),
                                batch)
         comp = lowered.compile()
@@ -52,7 +51,7 @@ def test_mini_dryrun_train_and_decode():
         tok = jax.ShapeDtypeStruct((8, 1), jnp.int32)
         fn2 = jax.jit(b2.fn, in_shardings=b2.in_shardings,
                       out_shardings=b2.out_shardings)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             comp2 = fn2.lower(api.param_shapes(cfg), cache, tok).compile()
         assert comp2.memory_analysis().argument_size_in_bytes > 0
         print("OK")

@@ -376,3 +376,38 @@ def test_heartbeat_virtual_clock_no_sleep():
     assert mon.stale() == []
     t["now"] = 20.0
     assert mon.stale() == ["s0"]
+
+
+def test_slow_shard_tick_is_not_a_failover():
+    """Shards step one after another, so one shard's long tick (a
+    first-call compile) ages every heartbeat stamped before it.  A shard
+    that beat during the tick is alive; only one that missed it can be
+    failed over."""
+    from repro.serving.engine import SimEngine
+
+    clk = {"t": 0.0}
+    plan = plan_fleet(2, ["m0", "m1"])
+
+    class Compiling(SimEngine):
+        def step(self):
+            out = super().step()
+            if self.queue and clk["t"] < 60.0:
+                clk["t"] += 60.0          # longer than the 5 s timeout
+            return out
+
+    def engine_factory(profile, spec):
+        cls = Compiling if spec.index == 1 else SimEngine
+        return cls(profile, lambda q, m: (0.5, 0.01, 10.0, 4),
+                   steps_per_query=2, clock=lambda: clk["t"])
+
+    ctrl = build_fleet(
+        plan, lambda spec: GreenServRouter(RouterConfig(max_arms=4),
+                                           _pool(2)),
+        engine_factory, heartbeat_timeout_s=5.0, clock=lambda: clk["t"])
+    ctrl.dispatch_many(_queries(4))
+    for _ in range(20):
+        if not ctrl.unanswered:
+            break
+        ctrl.step()
+    assert ctrl.stats["failovers"] == 0
+    assert ctrl.stats["completed"] == 4

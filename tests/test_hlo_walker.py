@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.launch.hlo_walker import module_cost, _shape_bytes
 
 
@@ -32,7 +31,7 @@ def test_walker_multiplies_scan_flops():
     true_flops = n * 2 * b * d * d
     assert cost.flops == pytest.approx(true_flops, rel=1e-6)
     # XLA's own analysis undercounts by the trip count
-    assert compat.cost_analysis(comp)["flops"] < true_flops / 2
+    assert comp.cost_analysis()["flops"] < true_flops / 2
 
 
 def test_walker_matches_unrolled():

@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs import ARCH_IDS, get_config
 from repro.models import api
 
@@ -53,7 +52,7 @@ def test_one_train_step_reduces_loss(arch):
                  out_shardings=bundle.out_shardings)
     params = api.init_params(cfg, jax.random.PRNGKey(0))
     opt = make_opt_state(cfg, params)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         losses = []
         for _ in range(4):
             params, opt, m = fn(params, opt, batch)

@@ -308,3 +308,56 @@ def test_real_engine_through_server():
         server.submit(q)
     server.run_until_drained(max_steps=2000)
     assert len(server.responses) == len(qs)
+
+
+def test_reused_slot_starts_from_zero_recurrent_state():
+    """rwkv's recurrent state is read whatever the slot's length says: a
+    request admitted into a slot another request just left must decode
+    exactly as it would on a fresh engine."""
+    def tokens(eng, prompts):
+        out = []
+        for uid, text in enumerate(prompts):
+            eng.submit(Request(query=Query(uid=uid, text=text),
+                               prompt_tokens=tok.encode(text),
+                               max_new_tokens=6))
+            for _ in range(200):
+                done = eng.step()
+                if done:
+                    out.append(done[0].tokens)
+                    break
+        return out
+
+    second = "Solve the math word problem step by step."
+    reused = tokens(_real_engine(max_batch=1),
+                    ["Summarize the following article.", second])
+    fresh = tokens(_real_engine(max_batch=1), [second])
+    assert reused[1] == fresh[0]
+
+
+def test_slow_tick_is_not_a_stalled_engine():
+    """A tick that outlasts the heartbeat timeout (a first-call compile)
+    is progress, not a stall: only an engine that completed no tick since
+    the previous health check may be restarted."""
+    clk = {"t": 0.0}
+    profiles = [ModelProfile(name=f"sim{i}", family="s", params_b=1.0)
+                for i in range(2)]
+
+    class Compiling(SimEngine):
+        def step(self):
+            out = super().step()
+            if self.queue and clk["t"] < 60.0:
+                clk["t"] += 60.0          # longer than the 30 s timeout
+            return out
+
+    engines = {p.name: Compiling(p, lambda q, m: (0.5, 0.01, 10.0, 4),
+                                 steps_per_query=3,
+                                 clock=lambda: clk["t"])
+               for p in profiles}
+    server = PoolServer(GreenServRouter(RouterConfig(max_arms=4),
+                                        ModelPool(profiles)),
+                        engines, clock=lambda: clk["t"])
+    for q in make_stream(per_task=1)[:2]:
+        server.submit(q)
+    server.run_until_drained(max_steps=50)
+    assert server.stats["restarts"] == 0
+    assert len(server.responses) == 2

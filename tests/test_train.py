@@ -94,13 +94,12 @@ def test_compressed_psum_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys; sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         from repro.launch.mesh import make_mesh
         from repro.train.compress import compressed_psum
         mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.standard_normal((8, 32, 16)), jnp.float32)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = compressed_psum({"w": g}, "data", mesh)
         want = np.asarray(g).sum(0)
         got = np.asarray(out["w"])
