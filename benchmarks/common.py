@@ -29,6 +29,7 @@ from repro.core.types import Feedback, Query, RouterConfig
 from repro.data import ENERGY_SCALE_WH, OutcomeSimulator
 from repro.data.scenarios import Scenario
 from repro.data.stream import labeled_sample, make_stream
+from repro.telemetry.spans import ROUTE_SPANS, Tracer
 
 
 @dataclasses.dataclass
@@ -41,7 +42,7 @@ class RunResult:
     selections: np.ndarray
     selection_trace: np.ndarray
     mean_decision_ms: float
-    feature_ms: Dict[str, float]
+    feature_ms: Dict[str, float]        # route.* span -> ms per routed query
 
 
 def make_router(lam: float = 0.4, algorithm: str = "linucb",
@@ -50,12 +51,22 @@ def make_router(lam: float = 0.4, algorithm: str = "linucb",
     cfg = RouterConfig(lam=lam, algorithm=algorithm, seed=seed,
                        energy_scale_wh=ENERGY_SCALE_WH, max_arms=32)
     pool = build_paper_pool(exclude=exclude)
-    router = GreenServRouter(cfg, pool)
+    # a tracer of its own, so route_span_ms reads this router alone
+    router = GreenServRouter(cfg, pool, tracer=Tracer())
     router.context.set_features(*features)
     if features[0]:
         texts, labels = labeled_sample(n_per_task=40, seed=seed + 1)
         router.context.task_classifier.fit(texts, labels, steps=150)
     return router
+
+
+def route_span_ms(router: GreenServRouter) -> Dict[str, float]:
+    """{route.* span: host ms per routed query} from the router's tracer
+    totals (``make_router`` gives each router a tracer of its own)."""
+    n = max(router.n_routed, 1)
+    return {name: 1e3 * seconds / n
+            for name, (_, seconds) in router.tracer.totals().items()
+            if name in ROUTE_SPANS and name != "feedback.update"}
 
 
 def run_policy(router: Optional[GreenServRouter], queries: Sequence[Query],
@@ -103,7 +114,7 @@ def run_policy(router: Optional[GreenServRouter], queries: Sequence[Query],
             router.feedback(Feedback(query_uid=q.uid, model_index=m_idx,
                                      accuracy=acc, energy_wh=e_wh,
                                      latency_ms=lat))
-    feature_ms = router.context.mean_overhead_ms() if router else {}
+    feature_ms = route_span_ms(router) if router else {}
     return RunResult(
         name=name, mean_accuracy=float(np.mean(accs)),
         total_energy_wh=energy,

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import functools
 import re
-import time
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -39,14 +38,6 @@ from repro.core.embedding import EmbeddingModel, tokenize
 from repro.core.residency import TransferLedger
 from repro.core.types import ContextVector, N_TASKS, RouterConfig
 
-
-def _sync(x):
-    """Block until device values are materialized — every ``timings_ms`` /
-    overhead timestamp must sit *after* a sync, or JAX's async dispatch
-    makes the reported per-query featurization overhead optimistic (the
-    clock would stop at enqueue, not completion).  Tolerates numpy/python
-    leaves; syncs only where timestamps are taken, never mid-pipeline."""
-    return jax.block_until_ready(x)
 
 # ---------------------------------------------------------------------------
 # Task classifier: LR over embeddings, trained with full-batch Adam in JAX.
@@ -497,10 +488,6 @@ class ContextGenerator:
         self.use_task = True
         self.use_cluster = True
         self.use_complexity = True
-        # "featurize" is the device path's host hashing pass; on the host
-        # path it stays 0 (hashing is inside the task/cluster stages there)
-        self.timings_ms = {"task": 0.0, "cluster": 0.0, "complexity": 0.0,
-                           "featurize": 0.0, "n": 0}
 
     def set_features(self, task: bool = True, cluster: bool = True,
                      complexity: bool = True) -> None:
@@ -548,28 +535,18 @@ class ContextGenerator:
         if not texts:
             return []
         n = len(texts)
-        t0 = time.perf_counter()
         if not self.use_task:
             task_labels = np.zeros(n, dtype=np.int64)
         elif task_labels is None:
             task_labels = self.task_classifier.predict_batch(texts)
-        _sync(task_labels)                    # timing boundary, not pipeline
-        t1 = time.perf_counter()
         if self.use_cluster:
             embs = (embeddings if embeddings is not None
                     else self.embedder.encode_batch(texts))
             clusters = [self.kmeans.update(e) for e in embs]
         else:
             clusters = [0] * n
-        _sync(clusters)
-        t2 = time.perf_counter()
         comp = ([self.complexity(t) for t in texts] if self.use_complexity
                 else [(100.0, 0)] * n)
-        t3 = time.perf_counter()
-        self.timings_ms["task"] += (t1 - t0) * 1e3
-        self.timings_ms["cluster"] += (t2 - t1) * 1e3
-        self.timings_ms["complexity"] += (t3 - t2) * 1e3
-        self.timings_ms["n"] += n
         return self.make_contexts(task_labels, clusters, comp)
 
     def make_contexts(self, task_labels, clusters, comp) -> List[ContextVector]:
@@ -640,16 +617,6 @@ class ContextGenerator:
     def classifier_params(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
         return self.task_classifier.w, self.task_classifier.b
 
-    def record_device_batch(self, n: int, featurize_ms: float,
-                            complexity_ms: float) -> None:
-        """Account a device-path batch in ``timings_ms`` (the fused
-        task+cluster+score time lives in the router's decision clock —
-        stages inside one jitted call cannot be timed separately without
-        syncing mid-pipeline)."""
-        self.timings_ms["featurize"] += featurize_ms
-        self.timings_ms["complexity"] += complexity_ms
-        self.timings_ms["n"] += n
-
     def probe_batch(self, texts: Sequence[str]
                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Read-only batched features for cache probes: (task labels,
@@ -686,10 +653,6 @@ class ContextGenerator:
         return (np.asarray(labels, dtype=np.int64)[:n],
                 np.asarray(clusters, dtype=np.int64)[:n],
                 np.asarray(emb)[:n])
-
-    def mean_overhead_ms(self) -> dict:
-        n = max(self.timings_ms["n"], 1)
-        return {k: v / n for k, v in self.timings_ms.items() if k != "n"}
 
     def state_dict(self) -> dict:
         return {"task": self.task_classifier.state_dict(),

@@ -27,8 +27,7 @@ import numpy as np
 
 from repro import compat
 from repro.core.bandits import NEG_INF, BanditPolicy
-from repro.core.context import (ContextGenerator, _sync,
-                                kmeans_update_scan)
+from repro.core.context import ContextGenerator, kmeans_update_scan
 from repro.core.pool import ModelPool
 from repro.core.rewards import RegretTracker, RewardManager, scalarize
 from repro.core.types import (ContextVector, Feedback, ModelProfile, Query,
@@ -36,12 +35,20 @@ from repro.core.types import (ContextVector, Feedback, ModelProfile, Query,
 from repro.kernels.featurize import hashed_embed
 from repro.kernels.featurize.ops import pad_pow2
 from repro.kernels.linucb import linucb_scores
+from repro.telemetry import spans
 
 
 # EWMA step for the per-arm baseline of offered cost-model predictions
 # (route_batch's energy_costs_wh tilt); slow enough that one odd batch
 # does not swing the baseline, fast enough to track load shifts
 _PRED_COST_BETA = 0.1
+
+
+def _sync(x):
+    """Block until device values are materialized: the decision clock
+    (``decision_ms_total``) stops after a sync, or JAX's async dispatch
+    would stop it at enqueue, not completion."""
+    return jax.block_until_ready(x)
 
 
 # args 7-9 are the resident k-means tuple: the call returns the updated
@@ -140,11 +147,21 @@ def _fused_decide(ids, weights, emb_in, labels_in, proj, w_clf, b_clf,
 
 
 class GreenServRouter:
-    """The paper's contribution as a composable module."""
+    """The paper's contribution as a composable module.
+
+    ``route_batch`` records ``route.features`` (Flesch counts, hashing,
+    padding — the host featurizer on the host path), ``route.decide``
+    (feasibility, arguments, the fused program and its sync, or the host
+    scorer), ``route.sync`` (reading the decision back) and
+    ``route.tilt`` (energy tilts, discounts, the decisions) into
+    ``tracer`` (default ``spans.TRACER``); ``feedback_batch`` records
+    ``feedback.update``."""
 
     def __init__(self, config: RouterConfig, pool: ModelPool,
-                 context: Optional[ContextGenerator] = None):
+                 context: Optional[ContextGenerator] = None,
+                 tracer: Optional[spans.Tracer] = None):
         self.config = config
+        self.tracer = tracer or spans.TRACER
         self.pool = pool
         self.context = context or ContextGenerator(config)
         self.policy = BanditPolicy(config, n_arms=len(pool))
@@ -304,6 +321,15 @@ class GreenServRouter:
         else:
             ctxs, arms, scores, feasible, t0 = self._featurize_score_host(
                 queries, embeddings, task_labels, blocked)
+        with self.tracer.span("route.tilt"):
+            return self._decisions(queries, ctxs, arms, scores, feasible, t0,
+                                   energy_discounts_wh, energy_costs_wh)
+
+    def _decisions(self, queries, ctxs, arms, scores, feasible, t0,
+                   energy_discounts_wh, energy_costs_wh
+                   ) -> List[RouteDecision]:
+        """The energy tilts and prefix discounts on the scores, the
+        decision clock, and the ``RouteDecision``s."""
         if energy_costs_wh is not None:
             c = np.asarray(energy_costs_wh, np.float64)
             if c.shape[0] != len(queries):
@@ -421,14 +447,17 @@ class GreenServRouter:
                               ) -> Tuple[list, np.ndarray, np.ndarray,
                                          np.ndarray, float]:
         """Reference path: host featurization, then the batched selector."""
-        ctxs = self.context.batch([q.text for q in queries],
-                                  embeddings=embeddings,
-                                  task_labels=task_labels)
+        tr = self.tracer
+        with tr.span("route.features"):
+            ctxs = self.context.batch([q.text for q in queries],
+                                      embeddings=embeddings,
+                                      task_labels=task_labels)
         t0 = time.perf_counter()
-        feasible = self._feasible_matrix(queries, blocked)
-        x = np.stack([c.vector for c in ctxs])
-        arms, scores = self.policy.select_batch(x, feasible)
-        _sync(scores)                 # timing boundary (route_batch's clock)
+        with tr.span("route.decide"):
+            feasible = self._feasible_matrix(queries, blocked)
+            x = np.stack([c.vector for c in ctxs])
+            arms, scores = self.policy.select_batch(x, feasible)
+            _sync(scores)             # timing boundary (route_batch's clock)
         return ctxs, arms, scores, feasible, t0
 
     def _featurize_score_device(self, queries: Sequence[Query],
@@ -439,40 +468,42 @@ class GreenServRouter:
                                            np.ndarray, float]:
         """Fused path: one host hashing pass, then ``_fused_decide``."""
         ctx = self.context
+        tr = self.tracer
         n = len(queries)
-        args, static, feasible, t0, feat_ms, comp_ms = self._device_inputs(
-            queries, embeddings, task_labels, blocked)
-        ctx.record_device_batch(n, feat_ms, comp_ms)
-        out = _fused_decide(*args, **static)
-        _sync(out)                    # timing boundary: the decision clock
+        with tr.span("route.features"):
+            feats = self._device_features(queries, embeddings, task_labels)
+        t0 = time.perf_counter()      # the decision clock starts here
+        with tr.span("route.decide"):
+            args, static, feasible = self._device_args(queries, feats,
+                                                       blocked)
+            out = _fused_decide(*args, **static)
+            _sync(out)                # timing boundary: the decision clock
         (arms_d, masked, labels, clusters, cent2, cnt2, ini2,
          comp_scores_d, comp_bins_d) = out
         if ctx.use_cluster:
             ctx.kmeans.load_device_state(cent2, cnt2, ini2)
         self.policy.advance_key()     # mirror select_batch's state step
-        comp = [(float(s), int(b)) for s, b in
-                zip(np.asarray(comp_scores_d, np.float32)[:n],
-                    np.asarray(comp_bins_d)[:n])]
-        ctxs = ctx.make_contexts(np.asarray(labels, dtype=np.int64)[:n],
-                                 np.asarray(clusters, dtype=np.int64)[:n],
-                                 comp)
-        return (ctxs, np.asarray(arms_d, dtype=np.int64)[:n],
-                np.asarray(masked, dtype=np.float32)[:n], feasible, t0)
+        with tr.span("route.sync"):
+            comp_scores = np.asarray(comp_scores_d, np.float32)[:n]
+            comp_bins = np.asarray(comp_bins_d)[:n]
+            labels = np.asarray(labels, dtype=np.int64)[:n]
+            clusters = np.asarray(clusters, dtype=np.int64)[:n]
+            arms = np.asarray(arms_d, dtype=np.int64)[:n]
+            scores = np.asarray(masked, dtype=np.float32)[:n]
+        comp = [(float(s), int(b)) for s, b in zip(comp_scores, comp_bins)]
+        ctxs = ctx.make_contexts(labels, clusters, comp)
+        return ctxs, arms, scores, feasible, t0
 
-    def _device_inputs(self, queries: Sequence[Query],
-                       embeddings: Optional[np.ndarray],
-                       task_labels: Optional[np.ndarray],
-                       blocked: Optional[np.ndarray] = None) -> tuple:
-        """Host half of the fused path: Flesch counts, the hashing pass and
-        the feasibility matrix, padded to a power of two.  Returns
-        ``(args, static, feasible, t0, featurize_ms, complexity_ms)`` for
-        ``_fused_decide``; ``t0`` starts the decision clock."""
+    def _device_features(self, queries: Sequence[Query],
+                         embeddings: Optional[np.ndarray],
+                         task_labels: Optional[np.ndarray]) -> dict:
+        """Host half of the fused path, part one: Flesch counts and the
+        hashing pass (or the forwarded embeddings/labels), padded to a
+        power of two."""
         ctx = self.context
         texts = [q.text for q in queries]
         n = len(texts)
-        tc0 = time.perf_counter()
         comp_counts = ctx.complexity_counts_batch(texts)
-        tc1 = time.perf_counter()
         need_emb = ctx.use_cluster and embeddings is None
         need_instr = ctx.use_task and task_labels is None
         emb_in = labels_in = None
@@ -502,9 +533,17 @@ class GreenServRouter:
         pad_rows = np.zeros((q_pad - n, 3), np.int32)
         pad_rows[:, 1] = 1            # sentences >= 1: padding rows never 0/0
         comp_counts = np.concatenate([comp_counts, pad_rows])
-        valid = np.arange(q_pad) < n
-        feat_ms = (time.perf_counter() - tc1) * 1e3
-        t0 = time.perf_counter()
+        return dict(ids=ids, weights=weights, emb_in=emb_in,
+                    labels_in=labels_in, comp_counts=comp_counts,
+                    valid=np.arange(q_pad) < n, mode=mode, q_pad=q_pad)
+
+    def _device_args(self, queries: Sequence[Query], feats: dict,
+                     blocked: Optional[np.ndarray] = None) -> tuple:
+        """Host half of the fused path, part two: the feasibility matrix
+        and the program's arguments.  Returns ``(args, static,
+        feasible)`` for ``_fused_decide``."""
+        ctx = self.context
+        n, q_pad = len(queries), feats["q_pad"]
         feasible = self._feasible_matrix(queries, blocked)
         feas_pad = np.zeros((q_pad, self.config.max_arms), bool)
         feas_pad[:n, : feasible.shape[1]] = feasible
@@ -520,26 +559,29 @@ class GreenServRouter:
             ini = jnp.int32(0)
         w_clf, b_clf = ctx.classifier_params()
         st = self.policy.state
-        args = (jnp.asarray(ids), jnp.asarray(weights), emb_in, labels_in,
+        args = (jnp.asarray(feats["ids"]), jnp.asarray(feats["weights"]),
+                feats["emb_in"], feats["labels_in"],
                 ctx.embedder.proj_device, w_clf, b_clf, cent, cnt, ini,
-                jnp.asarray(comp_counts), jnp.float32(ctx.complexity.lo),
+                jnp.asarray(feats["comp_counts"]),
+                jnp.float32(ctx.complexity.lo),
                 ctx.complexity.bin_width32, jnp.asarray(feas_pad),
-                jnp.asarray(valid), st.A_inv, st.theta, st.active)
-        static = dict(mode=mode, use_task=ctx.use_task,
+                jnp.asarray(feats["valid"]), st.A_inv, st.theta, st.active)
+        static = dict(mode=feats["mode"], use_task=ctx.use_task,
                       use_cluster=ctx.use_cluster,
                       use_complexity=ctx.use_complexity,
                       n_tasks=self.config.n_tasks,
                       n_clusters=self.config.n_clusters,
                       n_bins=self.config.n_complexity_bins,
                       alpha=float(self.config.alpha_ucb))
-        return args, static, feasible, t0, feat_ms, (tc1 - tc0) * 1e3
+        return args, static, feasible
 
     def lower_decide(self, queries: Sequence[Query]) -> "jax.stages.Lowered":
         """The device-path routing program ``route_batch`` would run for
         ``queries``, lowered and not run — to inspect what the device
         executes (e.g. that the Pallas kernels are compiled:
         ``tpu_custom_call`` in its text)."""
-        args, static, _, _, _, _ = self._device_inputs(queries, None, None)
+        args, static, _ = self._device_args(
+            queries, self._device_features(queries, None, None))
         return _fused_decide.lower(*args, **static)
 
     def feedback(self, fb: Feedback,
@@ -580,15 +622,16 @@ class GreenServRouter:
         in the returned reward list is None.
         """
         rewards: List[Optional[float]] = []
-        for i, fb in enumerate(fbs):
-            oracle = (oracle_rewards[i] if oracle_rewards is not None
-                      else None)
-            try:
-                rewards.append(self.feedback(fb, oracle))
-            except (KeyError, ValueError):
-                if strict:
-                    raise
-                rewards.append(None)
+        with self.tracer.span("feedback.update"):
+            for i, fb in enumerate(fbs):
+                oracle = (oracle_rewards[i] if oracle_rewards is not None
+                          else None)
+                try:
+                    rewards.append(self.feedback(fb, oracle))
+                except (KeyError, ValueError):
+                    if strict:
+                        raise
+                    rewards.append(None)
         return rewards
 
     def oracle_reward(self, acc_by_model: np.ndarray,

@@ -227,7 +227,8 @@ def main() -> None:
                        telemetry.events,
                        meta={"queries": len(queries), "wall_s": wall,
                              "lam": args.lam,
-                             "budget_wh": args.energy_budget_wh})
+                             "budget_wh": args.energy_budget_wh},
+                       spans=telemetry.tracer)
         print(f"[serve] wrote {n} telemetry rows to {args.metrics_out}")
 
 

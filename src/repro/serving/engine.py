@@ -43,6 +43,7 @@ from repro.core.types import ModelProfile
 from repro.models import api
 from repro.models.config import ModelConfig
 from repro.serving.request import Request, RequestState, Response
+from repro.telemetry import spans
 
 
 class EngineFailure(RuntimeError):
@@ -199,14 +200,23 @@ class ModelEngine(BaseEngine):
     Weights are stored in bf16 (``configs.for_mode(cfg, "serve")``).  With
     ``device`` set, params and cache are committed to that device, so every
     tick runs there (a fleet shard's replica on its own chip).
+
+    Each tick records ``engine.*:<name>`` spans into ``tracer`` (default
+    ``spans.TRACER``): the tick, its admission, packing, the jitted call
+    (``engine.dispatch.chunk`` / ``.decode``), the token read-back, the
+    per-slot advance with one ``engine.sync.length`` per finish test, the
+    prefix capture and the energy meter.
     """
 
     def __init__(self, name: str, cfg: ModelConfig, key: jax.Array,
                  max_batch: int = 4, max_len: int = 256,
                  params=None, detokenize: Optional[Callable] = None,
                  prefill_chunk: int = 1, role: str = "unified",
-                 device: Optional[jax.Device] = None):
+                 device: Optional[jax.Device] = None,
+                 tracer: Optional[spans.Tracer] = None):
         self.name = name
+        self.tracer = tracer or spans.TRACER
+        self._span = spans.engine_span_names(self.tracer, name)
         self.cfg = dataclasses.replace(for_mode(cfg, "serve"),
                                        kv_update="where")
         self.max_batch = max_batch
@@ -443,86 +453,102 @@ class ModelEngine(BaseEngine):
         that finished this tick."""
         if self._failed:
             raise EngineFailure(f"engine {self.name} failed")
-        self._admit()
-        self._last_step_s = time.monotonic()
-        if not any(self.slots):
-            return []
-        need_prefill = any(
-            req is not None and not req.defunct
-            and not req.prefill_done for req in self.slots)
-        if self._jit_chunk_step is not None and need_prefill:
-            out = self._chunk_tick()
-        else:
-            out = self._decode_tick()
-        # the heartbeat is the end of the last completed tick: a tick that
-        # spent a minute compiling still counts as progress
-        self._last_step_s = time.monotonic()
-        return out
+        tr, sn = self.tracer, self._span
+        with tr.span(sn.tick):
+            if self.queue:
+                with tr.span(sn.admit):
+                    self._admit()
+            self._last_step_s = time.monotonic()
+            if not any(self.slots):
+                return []
+            need_prefill = any(
+                req is not None and not req.defunct
+                and not req.prefill_done for req in self.slots)
+            if self._jit_chunk_step is not None and need_prefill:
+                out = self._chunk_tick()
+            else:
+                out = self._decode_tick()
+            # the heartbeat is the end of the last completed tick: a tick
+            # that spent a minute compiling still counts as progress
+            self._last_step_s = time.monotonic()
+            return out
 
     def _decode_tick(self) -> List[Response]:
         """Legacy one-token tick: every live slot feeds one token (next
         prompt token while prefilling, last generated token while
         decoding) through the jitted ``serve_step``."""
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            if not req.prefill_done:
-                tokens[i, 0] = req.prompt_tokens[req.n_prompt_fed]
-            else:
-                tokens[i, 0] = (req.generated[-1] if req.generated
-                                else req.prompt_tokens[-1])
-        next_tok, self.cache = self._jit_step(self.params, self.cache,
-                                              jnp.asarray(tokens))
-        next_tok = np.asarray(next_tok)
+        tr, sn = self.tracer, self._span
+        with tr.span(sn.pack):
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                if not req.prefill_done:
+                    tokens[i, 0] = req.prompt_tokens[req.n_prompt_fed]
+                else:
+                    tokens[i, 0] = (req.generated[-1] if req.generated
+                                    else req.prompt_tokens[-1])
+        with tr.span(sn.dispatch_decode):
+            next_tok, self.cache = self._jit_step(self.params, self.cache,
+                                                  jnp.asarray(tokens))
+        with tr.span(sn.sync_tokens):
+            next_tok = np.asarray(next_tok)
         self.n_steps += 1
-        # token-wise prefill runs the decode kernel, so it costs a decode
-        # step — but it is still prefill work, tagged as such
-        self._meter_step([
-            ("prefill" if not req.prefill_done else "decode", 1,
-             max(req.n_prompt_fed + len(req.generated), 1))
-            for req in self.slots
-            if req is not None and not req.defunct])
+        with tr.span(sn.meter):
+            # token-wise prefill runs the decode kernel, so it costs a
+            # decode step — but it is still prefill work, tagged as such
+            self._meter_step([
+                ("prefill" if not req.prefill_done else "decode", 1,
+                 max(req.n_prompt_fed + len(req.generated), 1))
+                for req in self.slots
+                if req is not None and not req.defunct])
         fed_prompt = [0 if (req is None or req.prefill_done) else 1
                       for req in self.slots]
-        return self._advance_slots(next_tok, fed_prompt)
+        with tr.span(sn.advance):
+            return self._advance_slots(next_tok, fed_prompt)
 
     def _chunk_tick(self) -> List[Response]:
         """Chunked-prefill tick: prefilling slots consume up to
         ``prefill_chunk`` prompt tokens, decode slots ride along with one
         token each (continuous batching never stalls), all in one jitted
         chunk-step."""
+        tr, sn = self.tracer, self._span
         C = self.prefill_chunk
-        tokens = np.zeros((self.max_batch, C), np.int32)
-        n_active = np.zeros((self.max_batch,), np.int32)
-        fed_prompt = [0] * self.max_batch
-        meter = []
-        for i, req in enumerate(self.slots):
-            if req is None or req.defunct:
-                continue
-            kv_start = req.n_prompt_fed + len(req.generated)
-            if not req.prefill_done:
-                n = min(C, len(req.prompt_tokens) - req.n_prompt_fed)
-                tokens[i, :n] = req.prompt_tokens[
-                    req.n_prompt_fed:req.n_prompt_fed + n]
-                n_active[i] = n
-                fed_prompt[i] = n
-                meter.append(("prefill", n, kv_start))
-            else:
-                tokens[i, 0] = (req.generated[-1] if req.generated
-                                else req.prompt_tokens[-1])
-                n_active[i] = 1
-                # decode rider in a mixed tick: its row is chunk-padded
-                # through the fused kernel (see chunk_rider_cost)
-                meter.append(("decode", 1, max(kv_start, 1), C))
-        next_tok, self.cache = self._jit_chunk_step(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(n_active))
-        next_tok = np.asarray(next_tok)
+        with tr.span(sn.pack):
+            tokens = np.zeros((self.max_batch, C), np.int32)
+            n_active = np.zeros((self.max_batch,), np.int32)
+            fed_prompt = [0] * self.max_batch
+            meter = []
+            for i, req in enumerate(self.slots):
+                if req is None or req.defunct:
+                    continue
+                kv_start = req.n_prompt_fed + len(req.generated)
+                if not req.prefill_done:
+                    n = min(C, len(req.prompt_tokens) - req.n_prompt_fed)
+                    tokens[i, :n] = req.prompt_tokens[
+                        req.n_prompt_fed:req.n_prompt_fed + n]
+                    n_active[i] = n
+                    fed_prompt[i] = n
+                    meter.append(("prefill", n, kv_start))
+                else:
+                    tokens[i, 0] = (req.generated[-1] if req.generated
+                                    else req.prompt_tokens[-1])
+                    n_active[i] = 1
+                    # decode rider in a mixed tick: its row is chunk-padded
+                    # through the fused kernel (see chunk_rider_cost)
+                    meter.append(("decode", 1, max(kv_start, 1), C))
+        with tr.span(sn.dispatch_chunk):
+            next_tok, self.cache = self._jit_chunk_step(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(n_active))
+        with tr.span(sn.sync_tokens):
+            next_tok = np.asarray(next_tok)
         self.n_steps += 1
         self.n_chunk_steps += 1
-        self._meter_step(meter)
-        return self._advance_slots(next_tok, fed_prompt)
+        with tr.span(sn.meter):
+            self._meter_step(meter)
+        with tr.span(sn.advance):
+            return self._advance_slots(next_tok, fed_prompt)
 
     def _advance_slots(self, next_tok: np.ndarray,
                        fed_prompt: List[int]) -> List[Response]:
@@ -562,7 +588,8 @@ class ModelEngine(BaseEngine):
     def _should_finish(self, slot: int, req: Request) -> bool:
         hit_eos = req.generated[-1] == req.eos_id
         full = len(req.generated) >= req.max_new_tokens
-        overflow = int(self.cache["length"][slot]) >= self.max_len - 1
+        with self.tracer.span(self._span.sync_length):
+            overflow = int(self.cache["length"][slot]) >= self.max_len - 1
         return hit_eos or full or overflow
 
     def _emit_migration(self, slot: int, req: Request) -> None:
@@ -661,12 +688,12 @@ class ModelEngine(BaseEngine):
         else:
             pre_wh, dec_wh = self._query_wh(len(req.prompt_tokens),
                                             req.prefix_reused, len(out))
-        ttft_ms = ((req.first_token_s - req.submit_s) * 1e3
+        ttft_ms = ((req.first_token_s - req.arrival_s) * 1e3
                    if req.first_token_s else 0.0)
         return Response(
             uid=req.uid, model_name=self.name, tokens=out,
             text=self.detokenize(out), latency_ms=req.latency_ms,
-            queue_ms=(req.start_s - req.submit_s) * 1e3,
+            queue_ms=(req.start_s - req.arrival_s) * 1e3,
             energy_wh=pre_wh + dec_wh, input_tokens=len(req.prompt_tokens),
             output_tokens=len(out), hedged_winner=req.hedged,
             ttft_ms=ttft_ms, prefix_reused=req.prefix_reused,
@@ -733,9 +760,12 @@ class ModelEngine(BaseEngine):
             # KV positions >= max_len that were never written — nothing
             # trustworthy to capture
             return
-        k = np.asarray(self.cache["k"][:, slot, :n_p])
-        v = np.asarray(self.cache["v"][:, slot, :n_p])
-        self.prefix_cache.insert(req.prompt_tokens, k, v)
+        tr, sn = self.tracer, self._span
+        with tr.span(sn.capture):
+            with tr.span(sn.sync_capture):
+                k = np.asarray(self.cache["k"][:, slot, :n_p])
+                v = np.asarray(self.cache["v"][:, slot, :n_p])
+            self.prefix_cache.insert(req.prompt_tokens, k, v)
 
     def restart(self) -> List[Request]:
         # defunct (cancelled/timed-out/failed) requests are dropped, not
@@ -928,7 +958,7 @@ class SimEngine(BaseEngine):
         resp = Response(
             uid=req.uid, model_name=self.name, tokens=[], text="",
             latency_ms=latency_ms,
-            queue_ms=(req.start_s - req.submit_s) * 1e3,
+            queue_ms=(req.start_s - req.arrival_s) * 1e3,
             energy_wh=pre_wh + dec_wh,
             input_tokens=n_in,
             output_tokens=out_tokens, ttft_ms=latency_ms,

@@ -26,8 +26,10 @@ class Request:
 
     Token counts are in tokenizer tokens; every ``*_s`` field is a
     ``time.monotonic()`` timestamp in seconds (0.0 = not reached yet):
-    ``submit_s`` at routing, ``start_s`` at slot admission (queue wait
-    ends), ``first_token_s`` at the first *generated* token (TTFT), and
+    ``arrival_s`` when the query reached the server (``PoolServer.enqueue``;
+    equal to ``submit_s`` where it was routed on arrival), ``submit_s`` at
+    routing, ``start_s`` at slot admission (queue wait ends),
+    ``first_token_s`` at the first *generated* token (TTFT), and
     ``finish_s`` at completion.  ``n_prompt_fed`` is the prompt cursor —
     how many prompt tokens the engine has consumed into the cache
     (advanced by 1 on the token-wise path, by up to ``prefill_chunk`` per
@@ -58,6 +60,7 @@ class Request:
     # against the metered energy_wh at completion
     predicted_wh: float = 0.0
     submit_s: float = dataclasses.field(default_factory=time.monotonic)
+    arrival_s: float = 0.0   # 0.0 = arrived when routed (submit_s)
     start_s: float = 0.0
     first_token_s: float = 0.0
     finish_s: float = 0.0
@@ -69,6 +72,10 @@ class Request:
     deadline_s: float = 0.0
     attempts: int = 0        # failed attempts so far (0 = first try clean)
     max_retries: int = 0     # re-dispatches allowed after the first attempt
+
+    def __post_init__(self):
+        if not self.arrival_s:
+            self.arrival_s = self.submit_s
 
     @property
     def uid(self) -> int:
@@ -103,8 +110,8 @@ class Request:
 @dataclasses.dataclass
 class Response:
     """The completed-request record the scheduler hands back: latencies in
-    milliseconds (``latency_ms`` end-to-end, ``queue_ms`` submission →
-    admission, ``ttft_ms`` submission → first generated token), energy in
+    milliseconds (``latency_ms`` submission → finish, ``queue_ms`` arrival
+    → slot admission, ``ttft_ms`` arrival → first generated token), energy in
     watt-hours (``energy_wh``, both phases), token counts in tokenizer
     tokens."""
 

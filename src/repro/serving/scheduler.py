@@ -40,6 +40,7 @@ from repro.core.types import Feedback, ModelProfile, Query, RouterConfig
 from repro.serving.engine import BaseEngine, EngineFailure
 from repro.serving.reliability import BreakerConfig, CircuitBreaker
 from repro.serving.request import Request, RequestState, Response
+from repro.telemetry import spans
 
 
 class LivelockError(TimeoutError):
@@ -58,7 +59,11 @@ class PoolServer:
     semantic reuse, consulted before routing), telemetry pre-binding — is
     applied through one ``_configure_engine`` choke point at construction
     and again on ``add_engine``, so a server-level setting governs the
-    whole pool including late joiners."""
+    whole pool including late joiners.
+
+    ``step`` records ``pool.*`` spans for its phases into ``tracer``
+    (default ``spans.TRACER``), and each completion writes its request's
+    ``request.*`` spans from the ``Request``'s own stamps."""
 
     def __init__(self, router: GreenServRouter,
                  engines: Dict[str, BaseEngine],
@@ -76,7 +81,8 @@ class PoolServer:
                  deadline_s: Optional[float] = None,
                  max_retries: int = 0,
                  retry_backoff_steps: int = 2,
-                 breaker_config: Optional[BreakerConfig] = None):
+                 breaker_config: Optional[BreakerConfig] = None,
+                 tracer: Optional[spans.Tracer] = None):
         names = router.pool.names
         missing = [n for n in names if n not in engines]
         if missing:
@@ -98,6 +104,7 @@ class PoolServer:
         # virtual-clock benches pass the clock their SimEngines share, so
         # submit/heartbeat timestamps never mix wall and modeled time
         self.clock = clock or time.monotonic
+        self.tracer = tracer or spans.TRACER
         self.accuracy_fn = accuracy_fn
         self.telemetry = telemetry
         self.prefill_chunk = prefill_chunk
@@ -141,6 +148,8 @@ class PoolServer:
         # continuous-batching arrivals queue: ``enqueue``d queries wait
         # here until a step() tick has free prefill capacity for them
         self.arrivals: List[Query] = []
+        # uid -> when ``enqueue`` parked it (Request.arrival_s)
+        self._arrival_s: Dict[int, float] = {}
         self.stats = {"hedges": 0, "restarts": 0, "completed": 0,
                       "cache_hits": 0, "migrations": 0, "deferred": 0,
                       "retries": 0, "timeouts": 0, "failed": 0,
@@ -265,10 +274,16 @@ class PoolServer:
         ``submit``, routing is deferred to admission time — the bandit
         sees the queue state that actually exists when the query gets a
         slot, and a burst never floods engine queues beyond what the
-        slots can absorb."""
+        slots can absorb.  The arrival is stamped now
+        (``Request.arrival_s``), so ``Response.queue_ms`` and ``ttft_ms``
+        count the wait for admission."""
+        self._arrival_s[query.uid] = self.clock()
         self.arrivals.append(query)
 
     def enqueue_many(self, queries: Sequence[Query]) -> None:
+        now = self.clock()
+        for q in queries:
+            self._arrival_s[q.uid] = now
         self.arrivals.extend(queries)
 
     def _admit_arrivals(self) -> None:
@@ -384,6 +399,7 @@ class PoolServer:
                         (int(probe[0][i]), int(probe[1][i]), probe[2][i]))
             hit, feats = self._try_semantic(query, feats_in)
             if hit is not None:
+                self._arrival_s.pop(query.uid, None)
                 req_by_uid[query.uid] = hit
             else:
                 routable.append(query)
@@ -414,10 +430,12 @@ class PoolServer:
         expected_savings_wh = 0.0
         predicted = [] if costs is not None else None
         for i, (query, decision) in enumerate(zip(routable, decisions)):
+            now = self.clock()
             req = Request(query=query, prompt_tokens=tokens[i],
                           max_new_tokens=query.max_new_tokens,
                           cache_features=miss_features[i],
-                          submit_s=self.clock(),
+                          submit_s=now,
+                          arrival_s=self._arrival_s.pop(query.uid, now),
                           deadline_s=(self.deadline_s or 0.0),
                           max_retries=self.max_retries)
             per_engine.setdefault(decision.model_name, []).append(req)
@@ -912,6 +930,7 @@ class PoolServer:
                 latency_ms=resp.latency_ms,
                 input_tokens=resp.input_tokens,
                 output_tokens=resp.output_tokens))
+        self._request_spans(primary_uid, primary.arrival_s, req)
         self.responses[primary_uid] = resp
         self.inflight.pop(primary_uid, None)
         self.hedges.pop(primary_uid, None)
@@ -943,6 +962,19 @@ class PoolServer:
                 # the energy budget for it (winner's cost as proxy)
                 self.telemetry.on_duplicate_work(resp.energy_wh)
 
+    def _request_spans(self, uid: int, arrival_s: float,
+                       req: Request) -> None:
+        """The request's life as ``request.*`` spans under its uid: queued
+        (arrival → routing), slot wait, prefill (slot → first token) and
+        decode (first token → finish); stamps an engine never set (a
+        simulated engine has no first token) leave their spans out."""
+        tr = self.tracer
+        stamps = (arrival_s, req.submit_s, req.start_s, req.first_token_s,
+                  req.finish_s)
+        for name, t0, t1 in zip(spans.REQUEST_SPANS, stamps, stamps[1:]):
+            if t0 and t1:
+                tr.record(name, t0, t1, uid)
+
     # -- main loop ---------------------------------------------------------------------
 
     def step(self) -> List[Response]:
@@ -953,50 +985,56 @@ class PoolServer:
         reached), the migration pump, one batched feedback flush, one
         telemetry/governor step.  Returns the responses completed this
         tick."""
-        done: List[Response] = []
-        self._step_idx += 1
-        self._check_engines()
-        self._check_deadlines()
-        self._admit_retries()
-        self._maybe_hedge()
-        self._admit_arrivals()
-        for name, eng in self.engines.items():
-            try:
-                for resp in eng.step():
+        tr = self.tracer
+        with tr.span("pool.step"):
+            done: List[Response] = []
+            self._step_idx += 1
+            with tr.span("pool.health"):
+                self._check_engines()
+                self._check_deadlines()
+                self._admit_retries()
+                self._maybe_hedge()
+            if self.arrivals:
+                with tr.span("pool.admit"):
+                    self._admit_arrivals()
+            for name, eng in self.engines.items():
+                self._step_engine(eng, name, name, done)
+            for name, twin in self.decode_engines.items():
+                self._step_engine(twin, name, f"{name}#decode", done)
+            with (tr.span("pool.migrate") if self.decode_engines
+                  else spans.NULL):
+                self._pump_migrations()
+            if self._fb_buffer:
+                with tr.span("pool.feedback"):
+                    self._flush_feedback()
+            for uid, req in self.inflight.items():
+                if req.state == RequestState.QUEUED:
+                    self.wait_steps[uid] = self.wait_steps.get(uid, 0) + 1
+            # telemetry last: power samples see the step's energy, and the
+            # governor's λ adjustment lands after this step's feedback flush
+            if self.telemetry is not None:
+                with tr.span("pool.telemetry"):
+                    self.telemetry.on_step(self._all_engines())
+            return done
+
+    def _step_engine(self, eng: BaseEngine, name: str, display: str,
+                     done: List[Response]) -> None:
+        """One tick of ``eng`` (a primary, or ``name``'s decode twin shown
+        as ``display``) and the handling of each response it returns; a
+        failed engine is restarted."""
+        try:
+            for resp in eng.step():
+                with self.tracer.span("pool.complete"):
                     req = self._find_request(resp.uid, name)
                     if req is None:
                         continue
                     if (getattr(resp, "corrupt", False)
-                            and self._handle_corrupt(resp, req, name)):
+                            and self._handle_corrupt(resp, req, display)):
                         continue
                     self._complete(resp, req)
                     done.append(resp)
-            except EngineFailure:
-                self._restart_engine(name)
-        for name, twin in self.decode_engines.items():
-            try:
-                for resp in twin.step():
-                    req = self._find_request(resp.uid, name)
-                    if req is None:
-                        continue
-                    if (getattr(resp, "corrupt", False)
-                            and self._handle_corrupt(resp, req,
-                                                     f"{name}#decode")):
-                        continue
-                    self._complete(resp, req)
-                    done.append(resp)
-            except EngineFailure:
-                self._restart_engine(name, decode=True)
-        self._pump_migrations()
-        self._flush_feedback()
-        for uid, req in self.inflight.items():
-            if req.state == RequestState.QUEUED:
-                self.wait_steps[uid] = self.wait_steps.get(uid, 0) + 1
-        # telemetry last: power samples see the step's energy, and the
-        # governor's λ adjustment lands after this step's feedback flush
-        if self.telemetry is not None:
-            self.telemetry.on_step(self._all_engines())
-        return done
+        except EngineFailure:
+            self._restart_engine(name, decode=display != name)
 
     def _all_engines(self) -> Dict[str, BaseEngine]:
         """Telemetry view of the pool: primaries under their model name,

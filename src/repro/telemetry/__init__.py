@@ -7,6 +7,8 @@ Turns "routing that measures energy" into "serving that governs energy":
   * ``budget``   — ``EnergyBudgetGovernor``: a Wh token bucket that
     modulates the router's λ online (``GreenServRouter.set_lambda``);
   * ``events``   — bounded structured event log;
+  * ``spans``    — ``Tracer``: program spans at each layer boundary of the
+    served path, in a bounded ring, on the profiler trace's host line too;
   * ``export``   — Prometheus text exposition + JSONL trace round-trip;
   * ``hub``      — the ``Telemetry`` facade ``PoolServer`` reports into.
 """
@@ -19,6 +21,7 @@ from repro.telemetry.hub import Telemetry
 from repro.telemetry.metrics import (Counter, Gauge, Histogram,
                                      MetricsRegistry, P2Quantile)
 from repro.telemetry.power import PowerSample, PowerTrace
+from repro.telemetry.spans import TRACER, Tracer
 
 __all__ = [
     "EnergyBudgetGovernor", "diurnal_carbon_intensity",
@@ -27,4 +30,5 @@ __all__ = [
     "Telemetry",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "P2Quantile",
     "PowerSample", "PowerTrace",
+    "TRACER", "Tracer",
 ]

@@ -10,6 +10,10 @@ Two consumers, two formats:
     the per-run artifact CI uploads so the perf trajectory is inspectable
     per-PR.
 
+Given a ``spans.Tracer``, both also carry its per-span totals, read at
+export time: ``greenserv_span_seconds_total{span=}`` and
+``greenserv_spans_total{span=}`` counters, and one ``span`` row per name.
+
 Both directions are lossless for the quantities they carry;
 ``tests/test_telemetry.py`` asserts the round-trips.
 """
@@ -23,6 +27,7 @@ from repro.telemetry.events import EventLog
 from repro.telemetry.metrics import (Counter, Gauge, Histogram,
                                      MetricsRegistry)
 from repro.telemetry.power import PowerTrace
+from repro.telemetry.spans import Tracer
 
 _LABEL_RE = re.compile(r'(\w+)="((?:[^"\\]|\\.)*)"')
 _LINE_RE = re.compile(r'^([a-zA-Z_:][\w:]*)(?:\{(.*)\})?\s+(\S+)$')
@@ -41,8 +46,10 @@ def _fmt_value(v: float) -> str:
     return repr(float(v))
 
 
-def to_prometheus(registry: MetricsRegistry) -> str:
-    """Render the registry in Prometheus text exposition format."""
+def to_prometheus(registry: MetricsRegistry,
+                  spans: Optional[Tracer] = None) -> str:
+    """Render the registry (and ``spans``' totals) in Prometheus text
+    exposition format."""
     lines: List[str] = []
     seen_header = set()
     for m in registry:
@@ -66,6 +73,15 @@ def to_prometheus(registry: MetricsRegistry) -> str:
                 f"{m.name}_sum{_fmt_labels(m.labels)} {_fmt_value(m.sum)}")
             lines.append(
                 f"{m.name}_count{_fmt_labels(m.labels)} {m.count}")
+    if spans is not None:
+        totals = spans.totals()
+        for name, help_, col in (
+                ("greenserv_span_seconds_total",
+                 "host seconds inside each program span", 1),
+                ("greenserv_spans_total", "program spans closed", 0)):
+            lines += [f"# HELP {name} {help_}", f"# TYPE {name} counter"]
+            lines += [f"{name}{_fmt_labels((('span', s),))} "
+                      f"{_fmt_value(t[col])}" for s, t in totals.items()]
     return "\n".join(lines) + "\n"
 
 
@@ -95,7 +111,8 @@ def parse_prometheus(text: str) -> Dict[Tuple[str, Tuple[Tuple[str, str], ...]],
 def to_jsonl_rows(registry: Optional[MetricsRegistry] = None,
                   power: Optional[PowerTrace] = None,
                   events: Optional[EventLog] = None,
-                  meta: Optional[dict] = None) -> Iterable[dict]:
+                  meta: Optional[dict] = None,
+                  spans: Optional[Tracer] = None) -> Iterable[dict]:
     if meta:
         yield {"type": "meta", **meta}
     if registry is not None:
@@ -116,15 +133,20 @@ def to_jsonl_rows(registry: Optional[MetricsRegistry] = None,
     if events is not None:
         for row in events.to_rows():
             yield {"type": "event", **row}
+    if spans is not None:
+        for name, count, seconds, self_s in spans.heaviest(len(spans.names)):
+            yield {"type": "span", "name": name, "count": count,
+                   "seconds": seconds, "self_seconds": self_s}
 
 
 def dump_jsonl(path: str, registry: Optional[MetricsRegistry] = None,
                power: Optional[PowerTrace] = None,
                events: Optional[EventLog] = None,
-               meta: Optional[dict] = None) -> int:
+               meta: Optional[dict] = None,
+               spans: Optional[Tracer] = None) -> int:
     n = 0
     with open(path, "w") as f:
-        for row in to_jsonl_rows(registry, power, events, meta):
+        for row in to_jsonl_rows(registry, power, events, meta, spans):
             f.write(json.dumps(row, sort_keys=True) + "\n")
             n += 1
     return n
@@ -133,7 +155,8 @@ def dump_jsonl(path: str, registry: Optional[MetricsRegistry] = None,
 def load_jsonl(path: str) -> Dict[str, List[dict]]:
     """Re-read a trace dump, grouped by row type."""
     out: Dict[str, List[dict]] = {"meta": [], "counter": [], "gauge": [],
-                                  "histogram": [], "power": [], "event": []}
+                                  "histogram": [], "power": [], "event": [],
+                                  "span": []}
     with open(path) as f:
         for line in f:
             line = line.strip()

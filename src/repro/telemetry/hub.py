@@ -38,6 +38,7 @@ from repro.telemetry.events import EventLog
 from repro.telemetry.metrics import Counter, Histogram, MetricsRegistry
 from repro.telemetry.power import (AVOIDED, PHASE_DECODE, PHASE_PREFILL,
                                    POOL, PowerTrace)
+from repro.telemetry.spans import REQUEST_SPANS, TRACER, Tracer
 from repro.core.energy import JOULES_PER_WH
 
 
@@ -47,8 +48,11 @@ class Telemetry:
                  power: Optional[PowerTrace] = None,
                  events: Optional[EventLog] = None,
                  governor: Optional[EnergyBudgetGovernor] = None,
-                 clock: Optional[Callable[[], float]] = None):
+                 clock: Optional[Callable[[], float]] = None,
+                 tracer: Optional[Tracer] = None):
         self.registry = registry if registry is not None else MetricsRegistry()
+        # the program spans whose heaviest ``summary`` lists
+        self.tracer = tracer or TRACER
         self.power = power if power is not None else PowerTrace()
         self.events = events if events is not None else EventLog()
         self.governor = governor
@@ -471,4 +475,21 @@ class Telemetry:
                     f"            avoided {avoided * 1e3:.4g} mWh credited "
                     f"(prefix {g['avoided_prefix_wh'] * 1e3:.4g} / semantic "
                     f"{g['avoided_semantic_wh'] * 1e3:.4g})")
+        # host work by self time; request spans are lifetimes, shown as
+        # the mean of each phase instead
+        tr = self.tracer
+        heavy = [row for row in tr.heaviest(len(tr.names))
+                 if row[0] not in REQUEST_SPANS][:10]
+        if heavy:
+            lines.append(f"  {'spans':38s} {'self s':>9s} {'total s':>12s} "
+                         f"{'count':>12s}")
+            lines += [f"    {name:36s} {self_s:9.4f} {sec:12.4f} {n:12d}"
+                      for name, n, sec, self_s in heavy]
+        totals = tr.totals()
+        phases = [(name.split(".", 1)[1], totals[name])
+                  for name in REQUEST_SPANS if name in totals]
+        if phases:
+            lines.append("  request   " + "   ".join(
+                f"{phase} {1e3 * sec / n:.1f} ms" for phase, (n, sec)
+                in phases) + "   (means)")
         return "\n".join(lines)

@@ -9,13 +9,13 @@ import time
 import numpy as np
 import pytest
 
-import repro.core.context as context_mod
 import repro.core.router as router_mod
 from repro.core.context import ContextGenerator, OnlineKMeans
 from repro.core.embedding import EmbeddingModel
 from repro.core.pool import ModelPool
 from repro.core.router import GreenServRouter
 from repro.core.types import Feedback, ModelProfile, Query, RouterConfig
+from repro.telemetry import spans
 
 WEIRD_TEXTS = [
     "Answer the question.\nWhat is the boiling point of water?",
@@ -330,26 +330,43 @@ def test_decision_clock_syncs_device_work(monkeypatch):
 
 
 def test_context_clock_syncs_stage_boundaries(monkeypatch):
+    """The host path's stages are spans: the featurizer's, then the
+    scorer's, which holds the sync that stops the decision clock."""
+    tracer = spans.Tracer()
+    r = GreenServRouter(RouterConfig(max_arms=16, featurize="host"),
+                        _pool(4), tracer=tracer)
     synced = []
-    real = context_mod._sync
-    monkeypatch.setattr(context_mod, "_sync",
-                        lambda x: (synced.append(True), real(x))[1])
-    gen = ContextGenerator(RouterConfig(featurize="host"))
+    real = router_mod._sync
+    monkeypatch.setattr(router_mod, "_sync", lambda x: (
+        synced.append((time.monotonic(),
+                       [s.name for s in tracer.records()[0]])), real(x))[1])
     t0 = time.perf_counter()
-    gen.batch(["Answer this.\nWhat?", "Summarize that.\nLong doc."])
+    r.route_batch(_queries(["Answer this.\nWhat?",
+                            "Summarize that.\nLong doc."], uid0=650))
     wall_ms = (time.perf_counter() - t0) * 1e3
-    assert len(synced) >= 2, "stage timestamps taken without sync"
-    stage_sum = sum(v for k, v in gen.timings_ms.items() if k != "n")
+    recs, _ = tracer.records()
+    assert [s.name for s in recs] == ["route.features", "route.decide",
+                                      "route.tilt"]
+    assert all(s.parent == -1 for s in recs)
+    # one sync, taken inside route.decide, after the featurizer closed
+    [(t_sync, closed)] = synced
+    assert closed == ["route.features"]
+    assert recs[1].start <= t_sync <= recs[1].end
+    stage_sum = sum(s.end - s.start for s in recs) * 1e3
     assert 0.0 < stage_sum <= wall_ms * 1.05
 
 
 def test_device_path_populates_featurize_timing():
-    r = _router("device")
+    tracer = spans.Tracer()
+    r = GreenServRouter(RouterConfig(max_arms=16, featurize="device"),
+                        _pool(4), tracer=tracer)
     r.route_batch(_queries(WEIRD_TEXTS[:4], uid0=700))
-    tm = r.context.timings_ms
-    assert tm["n"] == 4
-    assert tm["featurize"] >= 0.0 and tm["complexity"] > 0.0
-    assert r.mean_decision_ms > 0.0
+    totals = tracer.totals()
+    assert {k: c for k, (c, _) in totals.items()} == {
+        "route.features": 1, "route.decide": 1, "route.sync": 1,
+        "route.tilt": 1}
+    assert all(sec > 0.0 for _, sec in totals.values())
+    assert r.n_routed == 4 and r.mean_decision_ms > 0.0
 
 
 # ---------------------------------------------------------------------------
