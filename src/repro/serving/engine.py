@@ -203,9 +203,9 @@ class ModelEngine(BaseEngine):
 
     Each tick records ``engine.*:<name>`` spans into ``tracer`` (default
     ``spans.TRACER``): the tick, its admission, packing, the jitted call
-    (``engine.dispatch.chunk`` / ``.decode``), the token read-back, the
-    per-slot advance with one ``engine.sync.length`` per finish test, the
-    prefix capture and the energy meter.
+    (``engine.dispatch.chunk`` / ``.decode``), the token read-back (the
+    tick's one device read), the per-slot advance, the prefix capture and
+    the energy meter.
     """
 
     def __init__(self, name: str, cfg: ModelConfig, key: jax.Array,
@@ -575,21 +575,30 @@ class ModelEngine(BaseEngine):
                     # the first generated token gets the same finish checks
                     # as any decode token — an EOS-first or 1-token-budget
                     # request must not survive into decode (or migrate)
-                    if self._should_finish(i, req):
+                    if self._should_finish(req):
                         finished.append(self._finish(i))
                     elif self.role == "prefill":
                         self._emit_migration(i, req)
                 continue
             req.generated.append(int(next_tok[i]))
-            if self._should_finish(i, req):
+            if self._should_finish(req):
                 finished.append(self._finish(i))
         return finished
 
-    def _should_finish(self, slot: int, req: Request) -> bool:
+    @staticmethod
+    def _slot_length(req: Request) -> int:
+        """The slot's ``cache["length"]`` after a tick, from request progress
+        instead of a device read: every prompt token fed plus every
+        generated token but the newest, which is not fed yet.  A reset, a
+        prefix splice and a migration splice each set the device length to
+        the prompt cursor they leave, and every tick program adds exactly
+        the tokens it fed."""
+        return req.n_prompt_fed + len(req.generated) - 1
+
+    def _should_finish(self, req: Request) -> bool:
         hit_eos = req.generated[-1] == req.eos_id
         full = len(req.generated) >= req.max_new_tokens
-        with self.tracer.span(self._span.sync_length):
-            overflow = int(self.cache["length"][slot]) >= self.max_len - 1
+        overflow = self._slot_length(req) >= self.max_len - 1
         return hit_eos or full or overflow
 
     def _emit_migration(self, slot: int, req: Request) -> None:

@@ -39,7 +39,9 @@ COMPILE = "compile"
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 # the spans of the served path (docs/SERVING.md "Tracing"); engine spans
-# carry a ":<model>" suffix
+# carry a ":<model>" suffix.  ``engine.sync.length`` is not recorded (the
+# finish test takes slot lengths from the host); the name stays for the
+# readers of traces that hold it.
 POOL_SPANS = ("pool.step", "pool.health", "pool.admit", "pool.complete",
               "pool.migrate", "pool.feedback", "pool.telemetry")
 ROUTE_SPANS = ("route.features", "route.decide", "route.sync", "route.tilt",

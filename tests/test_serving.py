@@ -3,6 +3,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.cache.prefix import PrefixCache
 from repro.core.pool import ModelPool
 from repro.core.router import GreenServRouter
 from repro.core.types import ModelProfile, Query, RouterConfig, TaskType
@@ -361,3 +362,135 @@ def test_slow_tick_is_not_a_stalled_engine():
     server.run_until_drained(max_steps=50)
     assert server.stats["restarts"] == 0
     assert len(server.responses) == 2
+
+
+# -- the finish test's slot length: host against device ----------------------
+
+
+def _device_rule(eng):
+    """The finish test with the slot length read back from the device."""
+    def should_finish(req):
+        return (req.generated[-1] == req.eos_id
+                or len(req.generated) >= req.max_new_tokens
+                or int(eng.cache["length"][req.slot]) >= eng.max_len - 1)
+    return should_finish
+
+
+def _checked_host_rule(lengths):
+    """The engine's own finish test, its host slot length checked against
+    the device's at every call."""
+    def rule(eng):
+        host = eng._should_finish
+
+        def should_finish(req):
+            length = eng._slot_length(req)
+            assert length == int(eng.cache["length"][req.slot]), req.uid
+            lengths.append(length)
+            return host(req)
+        return should_finish
+    return rule
+
+
+def _engine_with(rule, arch, **kw):
+    cfg = get_config(arch, smoke=True, vocab_size=tok.VOCAB_SIZE)
+    eng = ModelEngine(arch, cfg, jax.random.PRNGKey(3), max_batch=2, **kw)
+    eng._should_finish = rule(eng)
+    return eng
+
+
+def _probe(uid, n_prompt, max_new, prompt_of=None):
+    """A request whose prompt is drawn from its uid (or ``prompt_of``'s)."""
+    base = uid if prompt_of is None else prompt_of
+    return Request(query=Query(uid=uid, text=f"probe {uid}"),
+                   prompt_tokens=[1 + (base * 37 + i) % 250
+                                  for i in range(n_prompt)],
+                   max_new_tokens=max_new, eos_id=-1)
+
+
+def _serve(eng, reqs, events, tick=0, until=300):
+    """Submit ``reqs`` and step ``eng`` from ``tick`` until they are answered
+    or ``until`` is reached, collecting (tick, uid, tokens) per response;
+    returns the next tick."""
+    for r in reqs:
+        eng.submit(r)
+    want = len(events) + len(reqs)
+    while len(events) < want and tick < until:
+        events += [(tick, r.uid, r.tokens) for r in eng.step()]
+        tick += 1
+    return tick
+
+
+def _rwkv_tokenwise(rule):
+    eng = _engine_with(rule, "rwkv6-1.6b", max_len=96)
+    events = []
+    _serve(eng, [_probe(0, 5, 6), _probe(1, 9, 1), _probe(2, 3, 8)], events)
+    assert len(events) == 3
+    return events
+
+
+def _dense_chunk_prefix_splice(rule):
+    eng = _engine_with(rule, "granite-3-8b", max_len=64, prefill_chunk=8)
+    eng.set_prefix_cache(PrefixCache(max_blocks=32, block_tokens=8))
+    events = []
+    tick = _serve(eng, [_probe(0, 20, 5)], events)
+    warm = _probe(1, 20, 7, prompt_of=0)
+    _serve(eng, [warm, _probe(2, 11, 9)], events, tick)
+    assert warm.prefix_reused == 16 and eng.prefix_hit_count() == 1
+    assert len(events) == 3
+    return events
+
+
+def _disaggregated_migration(rule):
+    eng = _engine_with(rule, "granite-3-8b", max_len=48, prefill_chunk=4,
+                       role="prefill")
+    twin = _engine_with(rule, "granite-3-8b", max_len=48, prefill_chunk=4,
+                        role="decode", params=eng.params)
+    for r in (_probe(0, 10, 5), _probe(1, 13, 1), _probe(2, 6, 4)):
+        eng.submit(r)
+    events, migrated = [], 0
+    for tick in range(100):
+        events += [(tick, r.uid, r.tokens) for r in eng.step()]
+        for r in eng.drain_migrations():
+            twin.submit_migrated(r)
+            migrated += 1
+        events += [(tick, r.uid, r.tokens) for r in twin.step()]
+        if len(events) == 3:
+            break
+    assert migrated == 2 and len(events) == 3
+    return events
+
+
+def _prompt_past_max_len(rule):
+    eng = _engine_with(rule, "granite-3-8b", max_len=32, prefill_chunk=8)
+    events = []
+    _serve(eng, [_probe(0, 40, 6), _probe(1, 20, 30)], events)
+    # both finish on overflow, well inside their budgets: the 40-token
+    # prompt at its first token, the other once its length reaches 31
+    assert sorted((uid, len(toks)) for _, uid, toks in events) == [
+        (0, 1), (1, 12)]
+    return events
+
+
+def _restart_mid_stream(rule):
+    eng = _engine_with(rule, "granite-3-8b", max_len=64, prefill_chunk=4)
+    events = []
+    tick = _serve(eng, [_probe(0, 9, 12), _probe(1, 14, 6), _probe(2, 5, 3)],
+                  events, until=6)
+    inflight = eng.restart()
+    assert inflight
+    _serve(eng, inflight, events, tick)
+    assert sorted(uid for _, uid, _ in events) == [0, 1, 2]
+    return events
+
+
+@pytest.mark.parametrize("case", [
+    _rwkv_tokenwise, _dense_chunk_prefix_splice, _disaggregated_migration,
+    _prompt_past_max_len, _restart_mid_stream], ids=lambda c: c.__name__[1:])
+def test_the_finish_test_reads_slot_lengths_from_the_host(case):
+    """At every finish test the engine's host slot length equals the
+    device's ``cache["length"]``, and the engine finishes the same requests
+    at the same ticks with the same tokens as with the device read."""
+    lengths = []
+    got = case(_checked_host_rule(lengths))
+    assert lengths
+    assert got == case(_device_rule)

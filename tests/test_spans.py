@@ -140,7 +140,7 @@ def _request(uid, n_prompt=2, max_new=30):
                    max_new_tokens=max_new, eos_id=-1)
 
 
-def test_a_decode_tick_reads_one_length_per_decoding_slot():
+def test_a_decode_tick_with_two_decoding_slots_reads_the_tokens_once():
     tr = spans.Tracer()
     eng = _engine(max_batch=3, tracer=tr)
     for uid in (1, 2):                       # two of three slots
@@ -162,8 +162,7 @@ def test_a_decode_tick_reads_one_length_per_decoding_slot():
             r = recs[r.parent - before]
         return r.parent == before + tick
     syncs = [r for r in recs if r.name.startswith("engine.sync.")]
-    assert sorted(r.name.split(":")[0] for r in syncs) == [
-        "engine.sync.length", "engine.sync.length", "engine.sync.tokens"]
+    assert [r.name.split(":")[0] for r in syncs] == ["engine.sync.tokens"]
     assert all(under_tick(r) for r in recs if r is not recs[tick])
     assert "engine.dispatch.decode" in names
     assert "engine.dispatch.chunk" not in names
