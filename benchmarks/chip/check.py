@@ -13,9 +13,10 @@ token's logit lies below the top one.
 The sample takes, from every model that served in the window, the request
 with the most served tokens (the longest), the longest of those that
 spliced a cached prefix, and others drawn from the seed, up to the
-configuration's count: it covers chunked prefill, prefix splices and
-decoding through the cache on the dense model, and token-wise prefill and
-decoding through the state on rwkv.
+configuration's count: it covers every path the models' longest requests
+took, such as chunked prefill, prefix splices and decoding through a
+cache, or token-wise prefill and decoding through a recurrent state.  Each
+model's reference is its layout's (``arch/<layout>.py``).
 
 The router's decisions in the window are checked beside the tokens
 (``route_ref.py``): their numbers sit under the name ``router``.

@@ -3,8 +3,11 @@
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``; everything about
 it is found by name: its configuration file (``configs``), its load file
 and the mix that names (``traffic/``), and a reader file for each of its
-per-layer metrics (``layers/<metric>.py``).  A new cell needs new entries
-and data files, and no edit here.
+per-layer metrics (``layers/<metric>.py``), and for each model of the
+configuration its layout's weights, plain reference and operation counts
+(``arch/<layout>.py``, see ``layouts.py``).  A new cell needs new entries
+and data files, and a new architecture one ``arch/<layout>.py`` beside its
+config file and cell; neither edits a file here.
 
 The deployment is built from the program's own parts: ``ModelEngine`` per
 model (weights from ``weights.py``), ``GreenServRouter``, ``GreenCache``

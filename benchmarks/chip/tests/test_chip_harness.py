@@ -11,6 +11,7 @@ not apply: the pool runs on one chip.)  The control in the next precision
 down, fp8 for the models and ``high`` for the router, is not correct under
 the cell's limits at the same size.
 """
+import collections
 import functools
 import json
 import os
@@ -91,6 +92,8 @@ def root(tmp_path_factory):
     bench["workloads"].append({"name": "tiny.short", "config": "tiny-pool",
                                "traffic": "tiny-short", "chips": 1,
                                "why": "test"})
+    for m in bench["per_layer"]:
+        m["workloads"].append("tiny.short")
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root
 
@@ -102,10 +105,140 @@ def test_a_new_cell_is_found_by_name_from_data_files(root):
         ["QA", "SUMMARIZATION"]
     assert {m["name"] for m in cell.end_to_end} == \
         {"setup_s", "tbt_p95_ms", "output_tokens_per_s"}
+    assert cell.per_layer
     for m in cell.per_layer:
         assert callable(harness.layer_reader(m["name"]))
     with pytest.raises(KeyError):
         harness.load_cell("no.such.cell", root)
+
+
+# A layout of its own, written as a new architecture's PR would write it:
+# residual ReLU layers and a head tied to the embedding.
+TOY_ARCH = '''
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+import reference
+from weights import _normal
+
+
+@dataclasses.dataclass(frozen=True)
+class Arch(reference.Arch):
+    norm_eps: float
+
+
+def arch(cfg):
+    return Arch(layout=cfg["layout"], norm_eps=1e-6)
+
+
+def leaf_specs(cfg):
+    L, d, V = cfg["n_layers"], cfg["d_model"], cfg["vocab_size"]
+    return [("tok/embed", (V, d), "bfloat16", "normal", 1.0, 0.0),
+            ("norm_f", (d,), "bfloat16", "ones", 0.0, 0.0),
+            _normal("layers/w", (L, d, d), d)]
+
+
+def hidden(params, tokens, a, quant=None):
+    x = params["tok"]["embed"][tokens].astype(jnp.float32)
+
+    def layer(x, w):
+        return x + jax.nn.relu(reference._mm(x, w.astype(jnp.float32),
+                                             quant)), None
+
+    x, _ = jax.lax.scan(layer, x, params["layers"]["w"])
+    return reference._rms(x, params["norm_f"].astype(jnp.float32),
+                          a.norm_eps)
+
+
+def head(params):
+    return params["tok"]["embed"].T
+
+
+def layer_matmul_params(cfg):
+    return cfg["d_model"] ** 2
+
+
+def weight_bytes(cfg):
+    d, L, V = cfg["d_model"], cfg["n_layers"], cfg["vocab_size"]
+    return 2 * (L * d * d + d * V + d)
+
+
+def token_flops(cfg, pos):
+    d, L, V = cfg["d_model"], cfg["n_layers"], cfg["vocab_size"]
+    return float(2 * L * layer_matmul_params(cfg) + 2 * d * V)
+
+
+def state_bytes(cfg, pos):
+    return 0.0
+
+
+def param_count(cfg):
+    d, L, V = cfg["d_model"], cfg["n_layers"], cfg["vocab_size"]
+    return {"layers": L * d * d, "embed_and_head": d * V, "final_norm": d}
+'''
+TOY = {"layout": "toy", "n_layers": 2, "d_model": 16, "vocab_size": 64}
+
+
+def test_a_new_architecture_is_found_by_name_from_its_module(root,
+                                                             monkeypatch):
+    """A toy layout's module, written into the checkout's ``arch/``, is
+    what the weights, the counts and the comparison use."""
+    import numpy as np
+
+    import check
+    import flops
+    import layouts
+    import reference
+    import weights
+
+    arch_dir = root / "benchmarks/chip/arch"
+    shutil.copytree(HERE / "arch", arch_dir, dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (arch_dir / "toy.py").write_text(TOY_ARCH)
+    monkeypatch.setattr(layouts, "ARCH_DIR", arch_dir)
+
+    params = weights.make(TOY, 3, 0, jax.devices()[0])
+    shapes = jax.tree.map(lambda a: (a.shape, str(a.dtype)), params)
+    assert shapes == {"tok": {"embed": ((64, 16), "bfloat16")},
+                      "norm_f": ((16,), "bfloat16"),
+                      "layers": {"w": ((2, 16, 16), "bfloat16")}}
+    assert sum(flops.param_count(TOY).values()) == sum(
+        a.size for a in jax.tree.leaves(params))
+    assert flops.step_cost(TOY, [0, 9]) == (
+        2 * (2 * 2 * 256 + 2 * 16 * 64.0), 2 * (2 * 256 + 16 * 64 + 16.0))
+
+    # greedy tokens of the toy's own reference, one position at a time
+    a = reference.Arch.of(TOY)
+    head = np.asarray(params["tok"]["embed"], np.float32).T
+    prompt, out = [5, 17, 40, 2], []
+    for _ in range(4):
+        hid = layouts.module("toy").hidden(
+            params, jnp.asarray([prompt + out]), a)
+        out.append(int(np.argmax(np.asarray(hid)[0, -1] @ head)))
+    Row = collections.namedtuple("Row", "model prompt generated")
+    kwargs = dict(weights={"toy": params}, models={"toy": TOY}, length=16,
+                  n_out=4, batch=2, controls=("fp8",))
+    sound = check.widest_gaps([Row("toy", prompt, out)], **kwargs)["toy"]
+    assert sound["tokens"] == 4 and sound["gap"] < 1e-3, sound
+    assert "control_gap.fp8" in sound
+    wrong = [out[0], (out[1] + 1) % 64] + out[2:]
+    broken = check.widest_gaps([Row("toy", prompt, wrong)], **kwargs)["toy"]
+    assert broken["gap"] > 0.1, broken
+
+
+@pytest.mark.parametrize("entry", ["weights.leaf_specs", "flops.token_flops",
+                                   "reference.Arch.of"])
+def test_a_layout_with_no_module_raises_naming_the_file(entry):
+    import flops
+    import reference
+    import weights
+    calls = {"weights.leaf_specs": lambda c: weights.leaf_specs(c),
+             "flops.token_flops": lambda c: flops.token_flops(c, 0),
+             "reference.Arch.of": lambda c: reference.Arch.of(c)}
+    with pytest.raises(ValueError, match=r"arch/no_such_layout\.py"):
+        calls[entry]({"layout": "no_such_layout", "d_model": 8})
 
 
 def _run_py(cwd, env):
