@@ -3,9 +3,11 @@
 The unit of storage is a *block*: ``block_tokens`` consecutive prompt
 tokens' worth of per-layer K and V for one model — numpy arrays of shape
 ``(n_layers, block_tokens, kv_heads, head_dim)`` each, in the engine's
-cache dtype.  Blocks live on the host (the device-side slot cache is
-transient per request; the pool is what survives across requests and
-engine restarts), so capacity is a host-memory knob, not an HBM one.
+cache dtype (or, for a latent-attention model, the latent rows
+``(n_layers, block_tokens, width)`` as K and no V).  Blocks live on the
+host (the device-side slot cache is transient per request; the pool is
+what survives across requests and engine restarts), so capacity is a
+host-memory knob, not an HBM one.
 
 The pool itself is policy-free: it stores, hands out, frees, and keeps a
 deterministic recency order (a monotonic operation counter, never wall
@@ -55,8 +57,13 @@ class KVBlockPool:
 
     # -- storage -------------------------------------------------------------
 
-    def put(self, k: np.ndarray, v: np.ndarray):
-        """Store one block; returns its id, or None when at capacity."""
+    @staticmethod
+    def _nbytes(k: np.ndarray, v) -> int:
+        return k.nbytes + (0 if v is None else v.nbytes)
+
+    def put(self, k: np.ndarray, v):
+        """Store one block (``v`` None for a latent cache); returns its id,
+        or None when at capacity."""
         if self.full:
             return None
         if k.shape[1] != self.block_tokens:
@@ -65,7 +72,7 @@ class KVBlockPool:
         bid = self._next_id
         self._next_id += 1
         self._blocks[bid] = (k, v)
-        self.nbytes += k.nbytes + v.nbytes
+        self.nbytes += self._nbytes(k, v)
         self.touch(bid)
         return bid
 
@@ -73,8 +80,7 @@ class KVBlockPool:
         return self._blocks[bid]
 
     def free(self, bid: int) -> None:
-        k, v = self._blocks.pop(bid)
-        self.nbytes -= k.nbytes + v.nbytes
+        self.nbytes -= self._nbytes(*self._blocks.pop(bid))
         self._last_used.pop(bid, None)
         self.evictions += 1
 

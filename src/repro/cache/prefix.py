@@ -92,23 +92,25 @@ class PrefixIndex:
     # -- growth / eviction ---------------------------------------------------
 
     def insert(self, tokens: Sequence[int], k: np.ndarray,
-               v: np.ndarray) -> int:
+               v: Optional[np.ndarray]) -> int:
         """Register a completed prompt's KV; returns blocks newly stored.
 
         ``k``/``v``: host arrays ``(n_layers, P, kv_heads, head_dim)``
-        covering at least the whole-block span of ``tokens``.  Existing
-        nodes on the path are only recency-bumped (dedup); new nodes are
-        allocated, evicting LRU leaves when the pool is at capacity.  If
-        every pooled block is an ancestor on the current path (pool far
-        smaller than one prompt), insertion stops rather than evicting
-        its own chain.
+        covering at least the whole-block span of ``tokens`` (a latent
+        cache's rows ``(n_layers, P, width)`` as ``k``, and ``v`` None).
+        Existing nodes on the path are only recency-bumped (dedup); new
+        nodes are allocated, evicting LRU leaves when the pool is at
+        capacity.  If every pooled block is an ancestor on the current path
+        (pool far smaller than one prompt), insertion stops rather than
+        evicting its own chain.
         """
         bt = self.pool.block_tokens
         node, added = self.root, 0
         on_path = set()
         # never index tokens the arrays don't cover (defense in depth —
         # the engine already skips overflowed prompts)
-        span = min(len(tokens), k.shape[1], v.shape[1])
+        span = min(len(tokens), k.shape[1],
+                   k.shape[1] if v is None else v.shape[1])
         for i in range(0, (span // bt) * bt, bt):
             key = tuple(tokens[i:i + bt])
             child = node.children.get(key)
@@ -116,8 +118,10 @@ class PrefixIndex:
                 while self.pool.full:
                     if not self._evict_leaf(protect=on_path):
                         return added
-                bid = self.pool.put(np.ascontiguousarray(k[:, i:i + bt]),
-                                    np.ascontiguousarray(v[:, i:i + bt]))
+                bid = self.pool.put(
+                    np.ascontiguousarray(k[:, i:i + bt]),
+                    None if v is None
+                    else np.ascontiguousarray(v[:, i:i + bt]))
                 child = _Node(key, node, bid)
                 node.children[key] = child
                 self._node_by_bid[bid] = child
@@ -168,7 +172,8 @@ class PrefixCache:
     def match(self, tokens: Sequence[int], max_tokens: int
               ) -> Tuple[int, Optional[np.ndarray], Optional[np.ndarray]]:
         """Longest usable prefix for a prompt: (p, k, v) with k/v shaped
-        ``(L, p, Hk, hd)``, or (0, None, None).  ``max_tokens`` caps the
+        ``(L, p, Hk, hd)`` (a latent cache's rows as k, v None), or
+        (0, None, None).  ``max_tokens`` caps the
         splice (an engine must keep >= 1 prompt token to feed, so it
         passes ``len(prompt) - 1``)."""
         if max_tokens <= 0:
@@ -178,11 +183,12 @@ class PrefixCache:
         if p <= 0:
             return 0, None, None
         k = np.concatenate([b[0] for b in blocks], axis=1)[:, :p]
-        v = np.concatenate([b[1] for b in blocks], axis=1)[:, :p]
+        v = (None if blocks[0][1] is None else
+             np.concatenate([b[1] for b in blocks], axis=1)[:, :p])
         return p, k, v
 
     def insert(self, tokens: Sequence[int], k: np.ndarray,
-               v: np.ndarray) -> int:
+               v: Optional[np.ndarray]) -> int:
         if len(tokens) < self.block_tokens:
             return 0
         return self.index.insert(tokens, k, v)

@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned archs + reduced smoke variants.
+"""Architecture registry: the assigned archs + reduced smoke variants.
 
 Usage:
     from repro.configs import get_config, ARCH_IDS, SHAPES
@@ -10,16 +10,17 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List
 
-from repro.configs import (gemma3_12b, gemma3_27b, granite_3_8b, grok_1_314b,
-                           h2o_danube_3_4b, llava_next_34b, qwen2_moe_a2_7b,
-                           rwkv6_1_6b, whisper_medium, zamba2_7b)
+from repro.configs import (deepseek_v2_lite, gemma3_12b, gemma3_27b,
+                           granite_3_8b, grok_1_314b, h2o_danube_3_4b,
+                           llava_next_34b, qwen2_moe_a2_7b, rwkv6_1_6b,
+                           whisper_medium, zamba2_7b)
 from repro.configs.shapes import (SHAPES, ShapeCell, cell_applicable,
                                   input_specs, text_len)
 from repro.models.config import ModelConfig, scaled_down
 
 _MODULES = [granite_3_8b, gemma3_27b, h2o_danube_3_4b, gemma3_12b,
             whisper_medium, zamba2_7b, llava_next_34b, rwkv6_1_6b,
-            grok_1_314b, qwen2_moe_a2_7b]
+            grok_1_314b, qwen2_moe_a2_7b, deepseek_v2_lite]
 
 REGISTRY: Dict[str, ModelConfig] = {m.ARCH_ID: m.CONFIG for m in _MODULES}
 ARCH_IDS: List[str] = list(REGISTRY)

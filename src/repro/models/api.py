@@ -11,7 +11,8 @@ One surface for the training loop, the serving engine, and the dry-run:
     serve_step(params, token, cache, cfg) one-token decode
     prefill_chunk(params, toks, cache, …) C-token prompt slab into the cache
     reset_slot(cache, slot)               zero one slot before reuse
-    splice_prefix(cache, slot, k, v)      reused prompt-prefix KV into a slot
+    splice_prefix(cache, slot, k, v)      reused prompt-prefix KV (or latent
+                                          rows, v unused) into a slot
     supports_chunked_prefill(cfg)         which layouts take the chunked path
 
 ``batch`` is a dict: tokens/labels (+ frames for enc-dec audio,
@@ -157,16 +158,17 @@ def serve_step(params: Params, token: jax.Array, cache: Params,
 def supports_chunked_prefill(cfg: ModelConfig) -> bool:
     """Whether ``prefill_chunk`` exists for this architecture family.
 
-    True for the attention-cached layouts (dense / moe / encdec) whose
-    decode cache is a full-depth positional KV store — a prompt slab can
-    be scattered in at arbitrary per-slot offsets.  Recurrent layouts
+    True for the attention-cached layouts (dense / moe / mla_moe / encdec)
+    whose decode cache is a full-depth positional store (k and v, or
+    mla_moe's one latent row per token under ``k``) — a prompt slab can be
+    scattered in at arbitrary per-slot offsets.  Recurrent layouts
     (rwkv, mamba_hybrid) carry per-token state whose batched chunking
     needs per-token activity gating inside the scan, and ring-buffer
     (windowed) caches would overwrite in-window keys mid-chunk; both keep
     the one-token path (the engine additionally checks the materialized
     cache for a ``k`` entry to exclude ring layouts).
     """
-    return cfg.layout in ("dense", "moe", "encdec")
+    return cfg.layout in ("dense", "moe", "mla_moe", "encdec")
 
 
 def reset_slot(cache: Params, slot) -> Params:
@@ -189,9 +191,10 @@ def splice_prefix(cache: Params, slot: int, k_block, v_block) -> Params:
     prompt whose first P tokens match this slot's prompt.  Valid exactly
     where chunked prefill is (full-depth positional ``k``/``v`` caches —
     dense/moe/encdec; the enc-dec splice covers the decoder self-KV only,
-    cross-KV is per-request).  The slot's length is set to P, so the
-    engine's next chunk step continues prefill at offset P — the cached
-    prefix is never recomputed.
+    cross-KV is per-request).  A latent cache (mla_moe) has ``k`` alone,
+    (L, P, r + rope) rows, and ``v_block`` is not read.  The slot's length
+    is set to P, so the engine's next chunk step continues prefill at
+    offset P — the cached prefix is never recomputed.
     """
     if "k" not in cache:
         raise ValueError(
@@ -199,9 +202,11 @@ def splice_prefix(cache: Params, slot: int, k_block, v_block) -> Params:
             f"cache keys {sorted(cache)} (ring-buffer and recurrent "
             f"layouts recompute their prompts)")
     from repro.models import attention as attn
-    k_c, v_c = attn.splice_kv(cache["k"], cache["v"], slot, k_block, v_block)
-    length = cache["length"].at[slot].set(k_block.shape[1])
-    return dict(cache, k=k_c, v=v_c, length=length)
+    out = dict(cache, length=cache["length"].at[slot].set(k_block.shape[1]))
+    for name, block in (("k", k_block), ("v", v_block)):
+        if name in cache:
+            out[name] = attn.splice_rows(cache[name], slot, block)
+    return out
 
 
 def prefill_chunk(params: Params, tokens: jax.Array, cache: Params,

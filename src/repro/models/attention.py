@@ -294,26 +294,20 @@ def attention_prefill_chunk(params: Params, x: jax.Array, k_cache: jax.Array,
     return shard(out, "act_btd"), (k_cache, v_cache)
 
 
-def splice_kv(k_cache: jax.Array, v_cache: jax.Array, slot: int,
-              k_block: jax.Array, v_block: jax.Array
-              ) -> Tuple[jax.Array, jax.Array]:
-    """Write a reused prompt-prefix KV block into one slot's cache rows.
+def splice_rows(cache: jax.Array, slot: int, block: jax.Array) -> jax.Array:
+    """Write a reused prompt-prefix block into one slot's cache rows.
 
-    k_cache/v_cache: (L, B, S, Hk, hd) stacked per-layer caches;
-    k_block/v_block: (L, P, Hk, hd) host-captured KV for prompt positions
-    [0, P).  The admission-time counterpart of the chunk path's
-    scatter-at-offset write (``attention_prefill_chunk``): the block lands
-    at positions 0..P-1 of slot ``slot`` and every other slot's rows are
-    untouched, so a splice mid-serving never perturbs neighbours.  Runs
-    eagerly on the host path (slot admission), where ``slot``/``P`` are
-    Python ints — no jit retrace pressure.
+    cache: (L, B, S, ...) stacked per-layer cache (k, v, or a latent);
+    block: (L, P, ...) host-captured rows for prompt positions [0, P).  The
+    admission-time counterpart of the chunk path's scatter-at-offset write
+    (``attention_prefill_chunk``): the block lands at positions 0..P-1 of
+    slot ``slot`` and every other slot's rows are untouched, so a splice
+    mid-serving never perturbs neighbours.  Runs eagerly on the host path
+    (slot admission), where ``slot``/``P`` are Python ints — no jit
+    retrace pressure.
     """
-    k_block = jnp.asarray(k_block).astype(k_cache.dtype)
-    v_block = jnp.asarray(v_block).astype(v_cache.dtype)
-    p = k_block.shape[1]
-    k_cache = k_cache.at[:, slot, :p].set(k_block)
-    v_cache = v_cache.at[:, slot, :p].set(v_block)
-    return k_cache, v_cache
+    block = jnp.asarray(block).astype(cache.dtype)
+    return cache.at[:, slot, :block.shape[1]].set(block)
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,7 @@ FULL_WINDOW = -1  # sentinel: attention window covering the whole sequence
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    layout: str                       # dense | moe | rwkv | mamba_hybrid | encdec
+    layout: str                       # dense | moe | mla_moe | rwkv | mamba_hybrid | encdec
     n_layers: int
     d_model: int
     n_heads: int
@@ -39,6 +39,24 @@ class ModelConfig:
     moe_d_ff: int = 0                 # per-expert hidden dim
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True       # renormalise the chosen top-k gates
+    first_k_dense: int = 0            # mla_moe: leading layers with a dense MLP
+    experts_first: int = 0            # mla_moe: the held share of the routed
+    experts_held: int = 0             # experts, [first, first + held); 0 = all
+
+    # --- multi-head latent attention (mla_moe) ---
+    kv_lora_rank: int = 0             # latent c_kv width
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # --- YaRN rope scaling (factor 1 = plain rope) ---
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- SSM / hybrid ---
     ssm_state: int = 0                # Mamba2 state dim
@@ -80,8 +98,19 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if self.layout in ("dense", "moe", "encdec") and self.n_heads % max(self.n_kv_heads, 1):
             raise ValueError(f"{self.name}: n_heads must be a multiple of n_kv_heads")
-        if self.layout == "moe" and (self.n_experts <= 0 or self.top_k <= 0):
-            raise ValueError(f"{self.name}: moe layout needs n_experts/top_k")
+        if self.layout in ("moe", "mla_moe") and (self.n_experts <= 0
+                                                  or self.top_k <= 0):
+            raise ValueError(f"{self.name}: {self.layout} layout needs "
+                             f"n_experts/top_k")
+        if self.experts_held and self.layout != "mla_moe":
+            raise ValueError(f"{self.name}: only the mla_moe layout holds a "
+                             f"share of the experts")
+        if not (0 <= self.experts_first
+                and self.experts_first + self.n_held_experts <= max(
+                    self.n_experts, 1)):
+            raise ValueError(f"{self.name}: held experts [{self.experts_first}"
+                             f", +{self.experts_held}) outside "
+                             f"{self.n_experts}")
 
     # -- derived ---------------------------------------------------------------
 
@@ -89,6 +118,16 @@ class ModelConfig:
     def compute_heads(self) -> int:
         """Query heads actually computed/stored (TPU alignment padding)."""
         return self.pad_heads_to or self.n_heads
+
+    @property
+    def n_held_experts(self) -> int:
+        """Routed experts this chip holds (the share; all by default)."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def latent_dim(self) -> int:
+        """mla_moe: width of one cached row, ``c_kv`` then the rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def q_dim(self) -> int:
@@ -137,6 +176,13 @@ class ModelConfig:
             per_layer += self.n_experts * 3 * d * self.moe_d_ff
             per_layer += self.n_shared_experts * 3 * d * self.moe_d_ff
             per_layer += d * self.n_experts  # router
+        elif self.layout == "mla_moe":
+            per_layer += self._mla_params() + 2 * d
+            dense = self.first_k_dense * 3 * d * f
+            moe = (self.n_layers - self.first_k_dense) * (
+                (self.n_held_experts + self.n_shared_experts)
+                * 3 * d * self.moe_d_ff + d * self.n_experts)
+            return int(embed + d + self.n_layers * per_layer + dense + moe)
         elif self.layout == "rwkv":
             di = self.ssm_expand * d  # rwkv: d_ff channel-mix + time-mix proj
             per_layer += 4 * d * d + d * self.d_ff * 2 + 8 * d
@@ -156,11 +202,29 @@ class ModelConfig:
             n += enc + cross
         return int(n)
 
+    def _mla_params(self) -> int:
+        """One latent-attention block: q, the latent and rope-key
+        projection, the latent's norm, its up-projection to per-head k and
+        v, and the output projection."""
+        d, h, r = self.d_model, self.n_heads, self.kv_lora_rank
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        return (d * h * qk + d * self.latent_dim + r
+                + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                + h * self.v_head_dim * d)
+
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: routed top-k + shared only)."""
-        if self.layout != "moe":
+        """Parameters touched per token (MoE: routed top-k + shared only;
+        at a share of the experts, the held experts a token is expected to
+        reach, ``top_k * held / n_experts`` of them)."""
+        if self.layout not in ("moe", "mla_moe"):
             return self.param_count()
         d = self.d_model
+        if self.layout == "mla_moe":
+            reached = self.top_k * self.n_held_experts / self.n_experts
+            return int(self.param_count()
+                       - (self.n_layers - self.first_k_dense)
+                       * (self.n_held_experts - reached) * 3 * d
+                       * self.moe_d_ff)
         dense_part = self.param_count() - self.n_layers * (
             (self.n_experts - self.top_k) * 3 * d * self.moe_d_ff)
         return int(dense_part)
@@ -188,9 +252,14 @@ def scaled_down(cfg: ModelConfig, **overrides) -> ModelConfig:
         n_frontend_tokens=min(cfg.n_frontend_tokens, 16) if cfg.n_frontend_tokens else 0,
         remat=False,
     )
-    if cfg.layout == "moe":
+    if cfg.layout in ("moe", "mla_moe"):
         base.update(n_experts=min(cfg.n_experts, 8), top_k=min(cfg.top_k, 2),
                     moe_d_ff=64, n_shared_experts=min(cfg.n_shared_experts, 2))
+    if cfg.layout == "mla_moe":
+        base.update(n_layers=3, first_k_dense=min(cfg.first_k_dense, 1),
+                    experts_first=0, experts_held=0, kv_lora_rank=32,
+                    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                    yarn_original_len=64)
     if cfg.layout in ("mamba_hybrid",):
         base.update(ssm_state=16, attn_every=2, n_layers=5)
     if cfg.layout == "encdec":

@@ -1,4 +1,5 @@
-"""Decoder-only LM assembly: dense / MoE / RWKV6 / Mamba2-hybrid layouts.
+"""Decoder-only LM assembly: dense / MoE / MLA+MoE / RWKV6 / Mamba2-hybrid
+layouts.
 
 All homogeneous layer stacks run under a single ``lax.scan`` over stacked
 parameters (compile-time hygiene: one traced layer body regardless of depth —
@@ -7,7 +8,10 @@ Heterogeneous attention patterns (gemma3 5:1 local:global) are expressed as a
 per-layer ``window`` vector consumed inside the scan, and zamba2's shared
 attention block fires every ``attn_every`` layers via ``lax.cond`` with
 weights closed over (shared = same params every application, per the paper's
-description of Zamba2).
+description of Zamba2).  The MLA+MoE layout (DeepSeek-V2) stacks its
+attention over every layer and its feed-forward halves apart, the leading
+dense MLPs (``layers/mlp``) and the expert layers (``layers/moe``), and
+runs all of them in one scan with the latent cache in its carry.
 
 Public surface:
     lm_shapes(cfg)                          parameter ShapeDtypeStruct tree
@@ -26,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn
+from repro.models import mla
 from repro.models import moe as moe_lib
 from repro.models import rwkv as rwkv_lib
 from repro.models import ssm as ssm_lib
@@ -59,6 +64,10 @@ def _layer_shapes(cfg: ModelConfig) -> Params:
                 "attn": attn.attn_shapes(cfg),
                 "norm_mlp": rms_norm_shapes(cfg.d_model, dt),
                 "moe": moe_lib.moe_shapes(cfg)}
+    if cfg.layout == "mla_moe":
+        return {"norm_attn": rms_norm_shapes(cfg.d_model, dt),
+                "attn": mla.mla_shapes(cfg),
+                "norm_mlp": rms_norm_shapes(cfg.d_model, dt)}
     if cfg.layout == "rwkv":
         return {"ln1": rms_norm_shapes(cfg.d_model, dt),
                 "ln2": rms_norm_shapes(cfg.d_model, dt),
@@ -76,6 +85,13 @@ def lm_shapes(cfg: ModelConfig) -> Params:
         "norm_f": rms_norm_shapes(cfg.d_model, dt),
         "layers": _stack(_layer_shapes(cfg), cfg.n_layers),
     }
+    if cfg.layout == "mla_moe":
+        n_dense = cfg.first_k_dense
+        if n_dense:
+            shapes["layers"]["mlp"] = _stack(
+                mlp_shapes(cfg.d_model, cfg.d_ff, dt), n_dense)
+        shapes["layers"]["moe"] = _stack(moe_lib.moe_shapes(cfg),
+                                         cfg.n_layers - n_dense)
     if cfg.layout == "mamba_hybrid":
         shapes["shared_attn"] = {
             "norm_attn": rms_norm_shapes(cfg.d_model, dt),
@@ -113,6 +129,55 @@ def _moe_block(layer: Params, x, positions, window, cfg, shard):
                                rms_norm(x, layer["norm_mlp"], cfg.norm_eps),
                                cfg, use_pallas=cfg.use_pallas)
     return shard(x + m, "act_btd"), aux
+
+
+def _mla_layers(params: Params, x, cache, attend, cfg: ModelConfig,
+                shard: ShardFn, remat: bool = False):
+    """Every MLA+MoE layer in one scan.  ``attend(attn params, normed x,
+    layer index, cache) -> (out, cache)`` is the attention half; the
+    feed-forward half is layer i's dense MLP for i < first_k_dense, its
+    held experts after.  ``cache`` rides the scan's carry.
+
+    One scan, not one per stack: a loop of one dense layer is unrolled,
+    and its attention then asks for a layout of the whole cache other than
+    the loop's, a copy of the cache in and out of every tick.  The expert
+    half runs on the dense layers too (on the first expert layer's weights)
+    and the ``lax.cond`` keeps the dense MLP's result there: an expert
+    half inside a ``cond`` branch makes the compiler lay the whole expert
+    stacks out anew on every chunk tick.  So a tick computes one expert
+    layer more than the model needs."""
+    stacks = params["layers"]
+    n_dense = cfg.first_k_dense
+
+    def take(tree, i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), tree)
+
+    def body(carry, xs):
+        x, cache = carry
+        layer, i = xs
+        h, cache = attend(layer["attn"],
+                          rms_norm(x, layer["norm_attn"], cfg.norm_eps), i,
+                          cache)
+        x = x + h
+        h = rms_norm(x, layer["norm_mlp"], cfg.norm_eps)
+        out = moe_lib.held_experts_block(
+            take(stacks["moe"], jnp.maximum(i - n_dense, 0)), h, cfg)
+        if n_dense:
+            out = jax.lax.cond(
+                i < n_dense,
+                lambda h, out: mlp(take(stacks["mlp"], jnp.minimum(
+                    i, n_dense - 1)), h, cfg.jnp_dtype()),
+                lambda h, out: out, h, out)
+        return (shard(x + out, "act_btd"), cache), None
+
+    if remat:
+        body = jax.checkpoint(body)
+    per_layer = {k: stacks[k] for k in ("norm_attn", "attn", "norm_mlp")}
+    (x, cache), _ = jax.lax.scan(
+        body, (x, cache),
+        (per_layer, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    return x, cache
 
 
 # ---------------------------------------------------------------------------
@@ -157,6 +222,13 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: ModelConfig,
         x, auxes = jax.lax.scan(lambda c, xs: body(c, xs), x,
                                 (params["layers"], windows))
         aux = jnp.sum(auxes)
+
+    elif cfg.layout == "mla_moe":
+        x, _ = _mla_layers(
+            params, x, (),
+            lambda p, h, i, c: (mla.attention_full(p, h, cfg, shard), c),
+            cfg, shard, remat=cfg.remat)
+        aux = aux0
 
     elif cfg.layout == "rwkv":
         states = rwkv_lib.init_rwkv_state(cfg, b)
@@ -227,6 +299,10 @@ def cache_shapes(cfg: ModelConfig, batch: int, max_len: int) -> Params:
     their local layers instead of full-depth KV — gemma3-27b decode_32k is
     2.1 TB of KV with uniform caches and 0.4 TB with rings."""
     L = cfg.n_layers
+    if cfg.layout == "mla_moe":
+        # one latent row per token per layer: c_kv then the rope key
+        return {"k": sds((L, batch, max_len, cfg.latent_dim), cfg.dtype),
+                "length": sds((batch,), "int32")}
     if cfg.layout in ("dense", "moe"):
         windowed = (cfg.layout == "dense"
                     and cfg.attn_pattern in ("swa", "local_global")
@@ -276,7 +352,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
 
 
 # ---------------------------------------------------------------------------
-# Chunked serving prefill (dense / moe, full-depth caches)
+# Chunked serving prefill (dense / moe / mla_moe, full-depth caches)
 # ---------------------------------------------------------------------------
 
 
@@ -297,21 +373,39 @@ def prefill_chunk_step(params: Params, tokens: jax.Array, cache: Params,
     ``decode_step`` computes — the serving engine exploits that to run
     mixed ticks where decode slots ride along in slot 0 of the slab.
 
-    Only full-depth KV layouts chunk (dense/moe with a ``k`` cache);
+    Only full-depth caches chunk (dense/moe k and v, or the mla_moe
+    latent ``k``);
     ring-buffer (windowed) caches and recurrent layouts fall back to the
     one-token path — see ``api.supports_chunked_prefill``.
     """
-    if cfg.layout not in ("dense", "moe") or "k" not in cache:
+    if cfg.layout not in ("dense", "moe", "mla_moe") or "k" not in cache:
         raise ValueError(
             f"chunked prefill unsupported for layout={cfg.layout!r} / "
             f"cache keys {sorted(cache)} (ring-buffer caches and recurrent "
             f"state need per-token gating; use the one-token decode path)")
     dtype = cfg.jnp_dtype()
-    b, c = tokens.shape
+    c = tokens.shape[1]
     lengths = cache["length"]
     active = (jnp.arange(c, dtype=jnp.int32)[None, :]
               < n_active[:, None])                           # (B, C)
     x = shard(embed(params["tok"], tokens, dtype), "act_btd")
+
+    if cfg.layout == "mla_moe":
+        x, new_cache = _mla_chunk(params, x, cache["k"], lengths, active,
+                                  cfg, shard)
+    else:
+        x, new_cache = _kv_chunk(params, x, cache, lengths, active, cfg,
+                                 shard)
+    new_cache["length"] = lengths + n_active
+    x = rms_norm(x, params["norm_f"], cfg.norm_eps)
+    logits = unembed(params["tok"], x, dtype)
+    return shard(logits, "act_btv"), new_cache
+
+
+def _kv_chunk(params: Params, x, cache: Params, lengths, active,
+              cfg: ModelConfig, shard: ShardFn):
+    """A slab through dense / moe layers over full-depth k and v caches."""
+    dtype = cfg.jnp_dtype()
     s_max = cache["k"].shape[2]
     windows = jnp.asarray(cfg.layer_windows(s_max), jnp.int32)
 
@@ -337,10 +431,21 @@ def prefill_chunk_step(params: Params, tokens: jax.Array, cache: Params,
 
     x, (k_new, v_new) = jax.lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"], windows))
-    new_cache = {"k": k_new, "v": v_new, "length": lengths + n_active}
-    x = rms_norm(x, params["norm_f"], cfg.norm_eps)
-    logits = unembed(params["tok"], x, dtype)
-    return shard(logits, "act_btv"), new_cache
+    return x, {"k": k_new, "v": v_new}
+
+
+def _mla_chunk(params: Params, x, k_cache, lengths, active,
+               cfg: ModelConfig, shard: ShardFn):
+    """A slab through the MLA+MoE layers over the latent cache (L, B, S,
+    r + rope); each layer updates its own rows of it in place."""
+    def attend(p, h, i, k_all):
+        h, rows = mla.attention_chunk(
+            p, h, jax.lax.dynamic_index_in_dim(k_all, i, 0, False), lengths,
+            active, cfg, shard)
+        return h, jax.lax.dynamic_update_index_in_dim(k_all, rows, i, 0)
+
+    x, k_cache = _mla_layers(params, x, k_cache, attend, cfg, shard)
+    return x, {"k": k_cache}
 
 
 # ---------------------------------------------------------------------------
@@ -447,6 +552,10 @@ def decode_step(params: Params, token: jax.Array, cache: Params,
     HBM traffic for grok decode_32k — because straight-line DUS on a buffer
     with later reads defeats XLA's in-place analysis; see EXPERIMENTS §Perf.)
     """
+    if cfg.layout == "mla_moe":
+        # a one-token decode is the one-token slab, every slot fed
+        return prefill_chunk_step(params, token, cache, cfg,
+                                  jnp.ones(token.shape[:1], jnp.int32), shard)
     dtype = cfg.jnp_dtype()
     x = shard(embed(params["tok"], token, dtype), "dec_btd")
     length = cache["length"]

@@ -6,6 +6,13 @@ einsum, and results are scattered back.  FLOPs scale with top_k (not with
 n_experts), so the roofline MODEL_FLOPS/HLO_FLOPs ratio stays honest — a
 one-hot-einsum MoE would inflate compiled FLOPs by E/top_k.
 
+A second layer, ``held_experts_block``, serves one chip's share of the
+experts (``ModelConfig.experts_first`` / ``experts_held``): it routes over
+all ``n_experts``, computes every token routed to an expert it holds, with
+no capacity and so no dropped token, and adds the shared experts, which
+every chip computes alike.  The shares' routed parts add up to the whole
+layer's.
+
 Sharding: experts are small for the assigned archs (grok: 8, qwen2-moe: 60),
 so we use expert-tensor-parallelism — every device holds all experts with the
 expert hidden dim sharded over the "model" axis, and tokens stay local to
@@ -26,9 +33,9 @@ from repro.models.layers import Params, mlp, mlp_shapes, sds
 
 def moe_shapes(cfg: ModelConfig) -> Params:
     dt = cfg.param_dtype
-    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.n_held_experts
     shapes = {
-        "router": sds((d, e), dt),
+        "router": sds((d, cfg.n_experts), dt),
         "wi_gate": sds((e, d, f), dt),
         "wi_up": sds((e, d, f), dt),
         "wo": sds((e, f, d), dt),
@@ -38,11 +45,16 @@ def moe_shapes(cfg: ModelConfig) -> Params:
     return shapes
 
 
-def top_k_gating(logits: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
-    """Returns (weights (T,k) softmaxed over the chosen k, indices (T,k))."""
+def top_k_gating(logits: jax.Array, k: int, renormalize: bool = True
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Returns (weights (T,k), indices (T,k)) of the k largest logits: the
+    softmax over the chosen k (``renormalize``), or their share of the
+    softmax over every expert."""
     gates, idx = jax.lax.top_k(logits, k)
-    weights = jax.nn.softmax(gates, axis=-1)
-    return weights, idx
+    if renormalize:
+        return jax.nn.softmax(gates, axis=-1), idx
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.take_along_axis(probs, idx, axis=-1), idx
 
 
 def expert_capacity(n_tokens: int, n_experts: int, top_k: int,
@@ -68,7 +80,7 @@ def _dispatch_group(params: Params, xf: jax.Array, cfg: ModelConfig,
         from repro.kernels.moe_gating import ops as gate_ops
         weights, idx = gate_ops.topk_gating(logits, k)
     else:
-        weights, idx = top_k_gating(logits, k)
+        weights, idx = top_k_gating(logits, k, cfg.norm_topk_prob)
 
     # load-balancing auxiliary loss (Switch-style): E * Σ_e f_e · p_e
     probs = jax.nn.softmax(logits, axis=-1)
@@ -145,3 +157,37 @@ def moe_block(params: Params, x: jax.Array, cfg: ModelConfig,
     if cfg.n_shared_experts:
         out = out + mlp(params["shared"], x.reshape(b, s, d), cfg.jnp_dtype())
     return out.reshape(b, s, d), aux_loss
+
+
+def held_experts_block(params: Params, x: jax.Array,
+                       cfg: ModelConfig) -> jax.Array:
+    """x: (B, S, d) -> (B, S, d): this chip's share of a routed layer plus
+    the shared experts.
+
+    The gate is float32 over all ``n_experts``; each token's top-k weights
+    (``top_k_gating``) land on the held experts among its choices.  Every
+    held expert runs on every token and its output is weighted by that
+    token's weight for it, zero where the token did not choose it: no
+    capacity, no dropped token, at ``held`` expert passes a token (one
+    step reads each held expert's weights once whatever the batch)."""
+    b, s, d = x.shape
+    dt = cfg.jnp_dtype()
+    xf = x.reshape(b * s, d)
+    with jax.named_scope("moe.gate"):
+        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                            params["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        weights, idx = top_k_gating(logits, cfg.top_k, cfg.norm_topk_prob)
+        held = cfg.experts_first + jnp.arange(cfg.n_held_experts)
+        w = jnp.sum(jnp.where(idx[:, :, None] == held, weights[:, :, None],
+                              0.0), axis=1)                  # (T, held)
+    with jax.named_scope("moe.experts"):
+        g = jnp.einsum("td,edf->etf", xf, params["wi_gate"].astype(dt))
+        u = jnp.einsum("td,edf->etf", xf, params["wi_up"].astype(dt))
+        h = (jax.nn.silu(g) * u).astype(jnp.float32) * w.T[:, :, None]
+        out = jnp.einsum("etf,efd->td", h.astype(dt),
+                         params["wo"].astype(dt))
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe.shared"):
+            out = out + mlp(params["shared"], xf, dt)
+    return out.reshape(b, s, d)

@@ -611,9 +611,7 @@ class ModelEngine(BaseEngine):
         n_p = len(req.prompt_tokens)
         if n_p > self.max_len - 1:
             return
-        k = np.asarray(self.cache["k"][:, slot, :n_p])
-        v = np.asarray(self.cache["v"][:, slot, :n_p])
-        req.kv_payload = (k, v)
+        req.kv_payload = self._prompt_rows(slot, n_p)
         req.kv_migrated = n_p
         req.prefill_wh = self._prefill_phase_wh(req)
         req.state = RequestState.MIGRATING
@@ -772,9 +770,14 @@ class ModelEngine(BaseEngine):
         tr, sn = self.tracer, self._span
         with tr.span(sn.capture):
             with tr.span(sn.sync_capture):
-                k = np.asarray(self.cache["k"][:, slot, :n_p])
-                v = np.asarray(self.cache["v"][:, slot, :n_p])
+                k, v = self._prompt_rows(slot, n_p)
             self.prefix_cache.insert(req.prompt_tokens, k, v)
+
+    def _prompt_rows(self, slot: int, n_p: int):
+        """A slot's prompt region as host (k, v); a latent cache has one
+        array of rows under ``k`` and gives v None."""
+        return tuple(np.asarray(self.cache[name][:, slot, :n_p])
+                     if name in self.cache else None for name in ("k", "v"))
 
     def restart(self) -> List[Request]:
         # defunct (cancelled/timed-out/failed) requests are dropped, not

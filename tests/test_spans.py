@@ -169,6 +169,35 @@ def test_a_decode_tick_with_two_decoding_slots_reads_the_tokens_once():
     assert all(r.name.endswith(":rwkv6-1.6b") for r in recs)
 
 
+@pytest.mark.parametrize("kind", ["chunk", "decode"])
+def test_a_latent_attention_tick_reads_its_tokens_once(kind):
+    """deepseek-v2-lite (latent cache, chunked prefill of 8): a chunk tick
+    and a decode tick each record the tick, its dispatch and exactly one
+    token read, all under ``:<model>``."""
+    tr = spans.Tracer()
+    name = "deepseek-v2-lite"
+    cfg = get_config(name, smoke=True, vocab_size=tok.VOCAB_SIZE)
+    eng = ModelEngine(name, cfg, jax.random.PRNGKey(0), max_batch=3,
+                      max_len=96, prefill_chunk=8, tracer=tr)
+    assert eng.prefill_chunk == 8 and set(eng.cache) == {"k", "length"}
+    for uid in (1, 2):
+        eng.submit(_request(uid, n_prompt=11))
+    eng.step()                               # first slab of 8
+    if kind == "decode":
+        while any(r is not None and r.state != RequestState.DECODE
+                  for r in eng.slots):
+            eng.step()
+    before = len(tr.records()[0])
+    eng.step()
+    recs = tr.records()[0][before:]
+    names = [r.name.split(":")[0] for r in recs]
+    assert names.count("engine.tick") == 1
+    assert f"engine.dispatch.{kind}" in names
+    assert [n for n in names if n.startswith("engine.sync.")] == \
+        ["engine.sync.tokens"]
+    assert all(r.name.endswith(f":{name}") for r in recs)
+
+
 # -- the scheduler's spans ---------------------------------------------------
 
 

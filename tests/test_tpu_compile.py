@@ -73,12 +73,12 @@ def test_linucb_kernel_compiles_for_v5e(one_chip, n_queries):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _serving(arch, sharding):
-    cfg = dataclasses.replace(for_mode(get_config(arch), "serve"),
-                              kv_update="where")
+def _serving(arch, sharding, batch=SERVE_BATCH, **overrides):
+    cfg = dataclasses.replace(for_mode(get_config(arch, **overrides),
+                                       "serve"), kv_update="where")
     params = _on(sharding, api.param_shapes(cfg))
     cache = _on(sharding, jax.eval_shape(
-        lambda: api.init_cache(cfg, SERVE_BATCH, SERVE_LEN)))
+        lambda: api.init_cache(cfg, batch, SERVE_LEN)))
     return cfg, params, cache
 
 
@@ -108,3 +108,33 @@ def test_published_width_prefill_chunk_compiles_for_v5e(one_chip):
     compiled = greedy_chunk_step.lower(params, cache, tokens, n_active,
                                        cfg=cfg).compile()
     assert _fits_hbm(compiled)
+
+
+@pytest.mark.parametrize("chunk", [1, CHUNK])
+def test_deepseek_share_serving_steps_compile_for_v5e(one_chip, chunk):
+    """deepseek-v2-lite at one EP8 chip's share (experts 0-7 of 64) with
+    the benchmark cell's 16 slots: the one-token and the chunk step fit
+    the chip, and neither copies the whole latent cache or a whole stack
+    of expert weights (a layout change of either per tick would cost as
+    much as the step's own reads)."""
+    batch = 16
+    cfg, params, cache = _serving("deepseek-v2-lite", one_chip, batch=batch,
+                                  experts_held=8)
+    assert set(cache) == {"k", "length"}
+    tokens = jax.ShapeDtypeStruct((batch, chunk), jnp.int32,
+                                  sharding=one_chip)
+    if chunk == 1:
+        compiled = greedy_step.lower(params, cache, tokens, cfg=cfg).compile()
+    else:
+        n_active = jax.ShapeDtypeStruct((batch,), jnp.int32,
+                                        sharding=one_chip)
+        compiled = greedy_chunk_step.lower(params, cache, tokens, n_active,
+                                           cfg=cfg).compile()
+    assert _fits_hbm(compiled)
+    whole = {",".join(map(str, cache["k"].shape))} | {
+        ",".join(map(str, params["layers"]["moe"][k].shape))
+        for k in ("wi_gate", "wi_up", "wo")}
+    copies = [line for line in compiled.as_text().splitlines()
+              if " copy(" in line
+              and any(f"[{shape}]" in line for shape in whole)]
+    assert not copies, copies[:2]
